@@ -1,7 +1,7 @@
 """Serving throughput: legacy per-slot engine vs paged continuous batching,
 plus the shared-system-prompt multi-tenant prefix-cache workload, the QMC
 serving-format (quantized-weights) engine variant, and the sharded paged
-engine on a forced multi-device host mesh.
+engine on a (2, 2) mesh.
 
 Runs a fixed synthetic workload through both engines at slots ∈ {1, 4, 8},
 prints the standard ``name,us_per_call,derived`` CSV rows, and writes
@@ -11,11 +11,12 @@ per configuration, plus the memsys paged/prefix KV traffic summaries the
 share one system prompt and reports hit rate, prefill-token reduction and
 tokens/s with the cache on vs off. The weights section compares dense fp32
 against the QMC deployment format (the paper's configuration). The sharded
-section re-runs the paged engine in a subprocess under
-``XLA_FLAGS=--xla_force_host_platform_device_count=4`` on a (2 data, 2
-model) mesh — token parity with the single-device engine plus the
-per-shard Eq. (3)/(4) traffic split. The phase-breakdown section splits
-each configuration's wall clock into host / device / compile shares from
+section re-runs the paged engine on a (2 data, 2 model) mesh — in a child
+process on four forced host devices when the bench runs on the CPU,
+in-process on the accelerator's own devices otherwise (reported as not
+run with fewer than four) — token parity with the single-device engine
+plus the per-shard Eq. (3)/(4) traffic split. The phase-breakdown section
+splits each configuration's wall clock into host / device / compile shares from
 the engine's phase accounting (``repro.obs``) for fp32-vs-qmc decode and
 cached-vs-uncached prefill. The cost-attribution section re-runs the
 fp32-vs-qmc decode pair under ``obs.costs`` capture: per step width it
@@ -35,7 +36,9 @@ barrier / lag-trim counters.
   PYTHONPATH=src python -m benchmarks.serving
 
 ``BENCH_SERVING_OUT=path`` redirects the JSON; ``BENCH_SECTIONS=a,b``
-runs only the named sections (CI's drift check runs a fast subset).
+runs only the named sections (CI's drift check runs a fast subset). The
+JSON names the device it ran on; a section that records an error makes
+the bench exit nonzero.
 """
 from __future__ import annotations
 
@@ -223,9 +226,16 @@ def run() -> dict:
         results["pipeline"] = _measure_pipeline(params)
     if _enabled("sharded"):
         results["sharded"] = _measure_sharded()
+    results["device"] = {"platform": jax.devices()[0].platform,
+                         "kind": jax.devices()[0].device_kind,
+                         "count": len(jax.devices())}
     with open(OUT, "w") as f:
         json.dump(results, f, indent=2)
     print(f"serving/json,0,{os.path.abspath(OUT)}")
+    failed = [k for k, v in results.items()
+              if isinstance(v, dict) and "error" in v]
+    if failed:
+        raise SystemExit(f"bench sections failed: {failed}")
     return results
 
 
@@ -687,75 +697,84 @@ def _measure_pipeline(params) -> dict:
     return out
 
 
-_SHARDED_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import json
-import jax, numpy as np
-from repro.launch import mesh as meshlib
-from repro.models.config import ModelConfig
-from repro.models.model import init_params
-from repro.serve.engine import Request, ServeEngine
+def _sharded_runs(spec: dict) -> dict:
+    """Single-device vs (2 data, 2 model) mesh paged engine on the same
+    config and workload: tokens/s, decode calls, token parity."""
+    from repro.launch import mesh as meshlib
+    cfg = ModelConfig(**spec["cfg"])
 
-# the parent injects its OWN config + workload, so the subprocess can
-# never drift from what the in-process sections measured
-spec = json.loads(os.environ["BENCH_SHARDED_SPEC"])
-CFG = ModelConfig(**spec["cfg"])
+    def requests():
+        return [Request(uid=i, prompt=np.asarray(p, np.int32),
+                        max_new_tokens=spec["max_new"])
+                for i, p in enumerate(spec["prompts"])]
 
-def requests():
-    return [Request(uid=i, prompt=np.asarray(p, np.int32),
-                    max_new_tokens=spec["max_new"])
-            for i, p in enumerate(spec["prompts"])]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    out, toks = {}, {}
+    for label, mesh in (("1dev", None),
+                        ("mesh2x2", meshlib.make_mesh((2, 2),
+                                                      ("data", "model")))):
+        # one engine, two runs: mesh step sets are built per engine (only
+        # the mesh=None builders are lru-shared), so a fresh engine would
+        # pay its jit compiles inside the timed run — reuse the warmed
+        # engine instead (stats reset per run(), and with no prefix cache
+        # no state carries)
+        eng = ServeEngine(cfg, params, slots=8, max_len=spec["max_len"],
+                          page_size=spec["page"], mesh=mesh)
+        eng.run(requests())               # warm-up pays jit compiles
+        reqs = requests()
+        eng.run(reqs)
+        toks[label] = [r.out_tokens for r in reqs]
+        out[label] = {"tokens_per_s": eng.stats.tokens_per_s,
+                      "decode_calls": eng.stats.decode_steps}
+    out["token_parity"] = toks["1dev"] == toks["mesh2x2"]
+    return out
 
-params = init_params(CFG, jax.random.PRNGKey(0))
-out = {}
-toks = {}
-for label, mesh in (("1dev", None),
-                    ("mesh2x2", meshlib.make_mesh((2, 2),
-                                                  ("data", "model")))):
-    # one engine, two runs: mesh step sets are built per engine (only the
-    # mesh=None builders are lru-shared), so a fresh engine would pay its
-    # jit compiles inside the timed run — reuse the warmed engine instead
-    # (stats reset per run(), and with no prefix cache no state carries)
-    eng = ServeEngine(CFG, params, slots=8, max_len=spec["max_len"],
-                      page_size=spec["page"], mesh=mesh)
-    eng.run(requests())               # warm-up pays jit compiles
-    reqs = requests()
-    eng.run(reqs)
-    toks[label] = [r.out_tokens for r in reqs]
-    out[label] = {"tokens_per_s": eng.stats.tokens_per_s,
-                  "decode_calls": eng.stats.decode_steps}
-out["token_parity"] = toks["1dev"] == toks["mesh2x2"]
-print("RESULT" + json.dumps(out))
+
+# child process of a CPU parent: the forced host device count must be set
+# before jax initializes, and the parent has already initialized it
+_SHARDED_CHILD = r"""
+import json, os
+from benchmarks.serving import _sharded_runs
+print("RESULT" + json.dumps(_sharded_runs(
+    json.loads(os.environ["BENCH_SHARDED_SPEC"]))))
 """
 
 
 def _measure_sharded() -> dict:
-    """Paged engine on a forced 4-device host mesh (subprocess: the forced
+    """Paged engine on a (2, 2) mesh + the per-shard DSE traffic split.
 
-    device count must be set before jax initializes) + the per-shard
-    DSE traffic split for the mesh."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
-                                     "src") + os.pathsep + \
-        env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
-    env["BENCH_SHARDED_SPEC"] = json.dumps({
-        "cfg": CFG_KW, "page": PAGE, "max_len": MAX_LEN,
-        "max_new": MAX_NEW,
-        "prompts": [r.prompt.tolist() for r in _requests()]})
-    try:
-        proc = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT],
-                              env=env, capture_output=True, text=True,
-                              timeout=1200)
+    On a CPU parent the mesh runs in a child process on four forced host
+    devices. Anywhere else the parent holds the accelerator (a child
+    could not reach it), so the mesh runs in-process on the parent's own
+    devices, or not at all when there are fewer than four."""
+    spec = {"cfg": CFG_KW, "page": PAGE, "max_len": MAX_LEN,
+            "max_new": MAX_NEW,
+            "prompts": [r.prompt.tolist() for r in _requests()]}
+    if jax.default_backend() != "cpu":
+        if len(jax.devices()) < 4:
+            print("serving/sharded_2x2,0,not run: needs 4 devices")
+            return {"not_run": "needs 4 devices"}
+        out = _sharded_runs(spec)
+    else:
+        root = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "..")
+        env = dict(os.environ)
+        env["PYTHONPATH"] = os.pathsep.join(
+            [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")])
+        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        env["JAX_PLATFORMS"] = "cpu"
+        env["BENCH_SHARDED_SPEC"] = json.dumps(spec)
+        try:
+            proc = subprocess.run([sys.executable, "-c", _SHARDED_CHILD],
+                                  env=env, capture_output=True, text=True,
+                                  timeout=1200)
+        except subprocess.TimeoutExpired:
+            return {"error": "sharded subprocess timed out"}
         line = [l for l in proc.stdout.splitlines()
                 if l.startswith("RESULT")]
         if proc.returncode != 0 or not line:
             return {"error": proc.stderr[-2000:]}
         out = json.loads(line[0][len("RESULT"):])
-    except subprocess.TimeoutExpired:
-        return {"error": "sharded subprocess timed out"}
     print(f"serving/sharded_2x2,0,"
           f"{out['mesh2x2']['tokens_per_s']:.1f}tok/s "
           f"parity={out['token_parity']}")
@@ -775,5 +794,7 @@ def _measure_sharded() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     print("name,us_per_call,derived")
     run()

@@ -13,10 +13,11 @@ dispatch path ``kernels.ops.qmm_plan`` can pick:
   * ``dense``      — fp32 ``x @ w`` (what the serving weight plan
                      executes per call after its one-time dequant)
 
-On CPU the Pallas paths run ``interpret=True`` — those columns validate
-the tiling architecture, not kernel speed; compare them on a real TPU
-backend. Prints the standard ``name,us_per_call,derived`` CSV rows and
-writes ``BENCH_qmm.json`` (``BENCH_QMM_OUT`` overrides; ``BENCH_QMM_MS``
+On the CPU backend the Pallas paths run in interpret mode
+(``kernels.resolve_interpret``) — those columns validate the tiling
+architecture, not kernel speed; compare them on a real TPU backend.
+Prints the standard ``name,us_per_call,derived`` CSV rows and writes
+``BENCH_qmm.json`` (``BENCH_QMM_OUT`` overrides; ``BENCH_QMM_MS``
 narrows the M sweep, e.g. ``BENCH_QMM_MS=1,8``).
 
   PYTHONPATH=src python scripts/bench_qmm.py
@@ -58,6 +59,7 @@ def _time(fn, iters: int, warmup: int = 2) -> float:
 
 
 def run() -> dict:
+    interp = jax.default_backend() == "cpu"
     w = jax.random.normal(jax.random.PRNGKey(0), (K, N), jnp.float32)
     qt = quantize_qtensor(w, QMCConfig(rho=0.3, granularity="subtile"))
     w_exec = dequantize_qtensor(qt, jnp.float32)   # the weight plan's form
@@ -89,9 +91,10 @@ def run() -> dict:
               f"xla_vs_ref={row['xla_vs_ref']:.2f}x "
               f"dense={row['us_per_call']['dense']:.1f}us "
               f"pallas[{plan_p['path']}]="
-              f"{row['us_per_call']['pallas']:.0f}us(interp)")
+              f"{row['us_per_call']['pallas']:.0f}us"
+              f"{'(interp)' if interp else ''}")
     out = {"config": {"k": K, "n": N, "backend": jax.default_backend(),
-                      "pallas_interpret": jax.default_backend() != "tpu"},
+                      "pallas_interpret": interp},
            "rows": rows}
     with open(OUT, "w") as f:
         json.dump(out, f, indent=2)

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Regenerate BENCH_serving.json (serving throughput + prefix-cache
-# benchmark). CPU-only; run from anywhere.
+# benchmark) on whatever backend JAX finds (set JAX_PLATFORMS=cpu for
+# the CPU); run from anywhere.
 set -euo pipefail
 cd "$(dirname "$0")/.."
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m benchmarks.serving "$@"

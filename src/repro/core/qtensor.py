@@ -26,17 +26,6 @@ from repro.core.qconfig import QMCConfig
 from repro.core.quantizers import (fake_quant, noise_aware_scale_search,
                                    mse_scale_search, quantize_codes, qrange)
 
-# int4 halves the container footprint when the backend supports it.
-_INT4_OK = True
-try:  # pragma: no cover - environment probe
-    jnp.zeros((8,), dtype=jnp.int4).astype(jnp.float32)
-except Exception:  # pragma: no cover
-    _INT4_OK = False
-
-
-def inlier_container_dtype():
-    return jnp.int4 if _INT4_OK else jnp.int8
-
 
 @partial(jax.tree_util.register_dataclass,
          data_fields=["in_codes", "out_codes", "stream_pos", "is_out",
@@ -120,7 +109,7 @@ def quantize_qtensor(w: jax.Array, cfg: QMCConfig,
                 .reshape(n_sub, r, c))
 
     t_in = tiles_of(codes_in)[in_ids].astype(
-        inlier_container_dtype() if use_int4 else jnp.int8)
+        jnp.int4 if use_int4 else jnp.int8)
     t_out = tiles_of(codes_out)[out_ids].astype(jnp.int8)
 
     pos = np.zeros(n_sub, np.int32)

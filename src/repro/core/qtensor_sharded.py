@@ -103,7 +103,6 @@ def qmm_shard_map(x: jax.Array, sqt: ShardedQTensor, mesh,
     exactly as on the single-device path.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.kernels import ops as kops
 
@@ -127,14 +126,14 @@ def qmm_shard_map(x: jax.Array, sqt: ShardedQTensor, mesh,
     if sqt.shard_axis == 1:
         def body(xl, q):
             return kops.qmm(xl, q.local(0), use_pallas=use_pallas)
-        y = shard_map(body, mesh=mesh,
-                      in_specs=(P(bspec, None), qt_specs),
-                      out_specs=P(bspec, axis))(x2, sqt)
+        y = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P(bspec, None), qt_specs),
+                          out_specs=P(bspec, axis), check_vma=False)(x2, sqt)
     else:
         def body(xl, q):
             yl = kops.qmm(xl, q.local(0), use_pallas=use_pallas)
             return jax.lax.psum(yl, axis)
-        y = shard_map(body, mesh=mesh,
-                      in_specs=(P(bspec, axis), qt_specs),
-                      out_specs=P(bspec, None))(x2, sqt)
+        y = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P(bspec, axis), qt_specs),
+                          out_specs=P(bspec, None), check_vma=False)(x2, sqt)
     return y.reshape(*lead, sqt.shape[1])

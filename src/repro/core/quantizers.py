@@ -75,6 +75,8 @@ def mse_scale_search(w: jax.Array, bits: int, channel_axis: int = -1,
         grid_lo=grid_lo, grid_hi=grid_hi, grid_n=grid_n, mask=mask)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "bits", "noise", "channel_axis", "grid_lo", "grid_hi", "grid_n"))
 def noise_aware_scale_search(
         w: jax.Array, bits: int, noise: Optional[NoiseModel],
         channel_axis: int = -1, grid_lo: float = 0.3, grid_hi: float = 1.05,
@@ -84,7 +86,8 @@ def noise_aware_scale_search(
     With `noise=None` this degrades to the plain MSE objective (Step 3);
     otherwise the per-channel inlier count times (p-+p+) * s^2 penalizes
     large steps (Step 2). Runs as a fori-loop over grid points so peak
-    memory stays O(|W|) instead of O(|W| * grid_n).
+    memory stays O(|W|) instead of O(|W| * grid_n); jitted, so PTQ over
+    many same-shaped matrices compiles the loop once per shape.
     """
     ch = channel_axis % w.ndim
     red = tuple(a for a in range(w.ndim) if a != ch)

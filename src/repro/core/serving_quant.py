@@ -40,8 +40,7 @@ import numpy as np
 
 from repro.core.apply import is_quantizable, path_str
 from repro.core.qconfig import QMCConfig
-from repro.core.qtensor import (QTensor, inlier_container_dtype,
-                                quantize_qtensor)
+from repro.core.qtensor import QTensor, quantize_qtensor
 from repro.core.qtensor_sharded import (ShardedQTensor,
                                         quantize_qtensor_sharded)
 
@@ -79,7 +78,7 @@ def qtensor_struct(din: int, dout: int, cfg: QMCConfig,
     r, c = cfg.subtile
     gr, gc, k_in, k_out = stream_sizes(din, dout, cfg)
     sds = jax.ShapeDtypeStruct
-    idt = inlier_container_dtype() if use_int4 else jnp.int8
+    idt = jnp.int4 if use_int4 else jnp.int8
     return QTensor(
         in_codes=sds((k_in, r, c), idt),
         out_codes=sds((k_out, r, c), jnp.int8),
@@ -212,7 +211,10 @@ def build_exec_weights(params, dtype=jnp.float32):
             return leaf
         for _ in range(extra):               # stacked [G]/[G, E] leaves
             fn = jax.vmap(fn)
-        return fn(leaf)
+        # one jit per leaf: XLA fuses gather/select/scale into the output
+        # buffer, where op-by-op dispatch would hold several dense-sized
+        # temporaries at once (GBs for a full-width stack or LM head)
+        return jax.jit(fn)(leaf)
 
     return jax.tree_util.tree_map(lower, params, is_leaf=is_q)
 

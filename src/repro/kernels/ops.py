@@ -31,13 +31,6 @@ from repro.kernels import ref as kref
 _SKINNY_XLA_MAX_M = 2
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
 def qmm_plan(m: int, k: int, n: int, subtile: tuple[int, int],
              use_pallas: bool = False) -> dict:
     """Pick the qmm lowering for an [m, k] @ [k, n] call.
@@ -103,12 +96,10 @@ def qmm(x: jax.Array, qt: QTensor, use_pallas: bool = False) -> jax.Array:
         if plan["pad_m"] != m:
             x2 = jnp.pad(x2, ((0, plan["pad_m"] - m), (0, 0)))
         if plan["path"] == "colstrip":
-            y = qmm_pallas_colstrip(x2, qt, block_m=plan["block_m"],
-                                    interpret=not _on_tpu())
+            y = qmm_pallas_colstrip(x2, qt, block_m=plan["block_m"])
         else:
             y = qmm_pallas(x2, qt, block_m=plan["block_m"],
-                           block_n=plan["block_n"],
-                           interpret=not _on_tpu())
+                           block_n=plan["block_n"])
         return y[:m].reshape(*lead, n)
     if plan["path"] == "skinny_xla":
         return qmm_skinny(x2, qt).reshape(*lead, n)
@@ -121,6 +112,5 @@ def unpack3b(packed: jax.Array, n: int, use_pallas: bool = False
         block = 1024 if n % 1024 == 0 else (8 if n % 8 == 0 else None)
         if block is not None:
             from repro.kernels.unpack3b import unpack3b_pallas
-            return unpack3b_pallas(packed, n, block_codes=block,
-                                   interpret=not _on_tpu())
+            return unpack3b_pallas(packed, n, block_codes=block)
     return kref.unpack3b_ref(packed, n)

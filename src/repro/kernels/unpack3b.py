@@ -10,10 +10,13 @@ word-aligned loads, shifts and masks (VPU-friendly; no gathers).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _unpack3b_kernel(p_ref, o_ref, *, codes_per_block: int):
@@ -28,7 +31,7 @@ def _unpack3b_kernel(p_ref, o_ref, *, codes_per_block: int):
 
 
 def unpack3b_pallas(packed: jax.Array, n: int, *, block_codes: int = 1024,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """Decode `n` 3-bit codes from a packed uint8 stream.
 
     n must be a multiple of 8 and of block_codes; the stream length must be
@@ -47,5 +50,5 @@ def unpack3b_pallas(packed: jax.Array, n: int, *, block_codes: int = 1024,
         in_specs=[pl.BlockSpec((bytes_per_block,), lambda i: (i,))],
         out_specs=pl.BlockSpec((block_codes,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(packed)

@@ -20,18 +20,19 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Mesh over the first prod(shape) devices (the dry-run host exposes
-
-    512 placeholder devices; the single-pod mesh uses the first 256)."""
-    import numpy as np
-    from jax.sharding import Mesh
+    """Mesh over prod(shape) devices laid out along the physical topology
+    (``jax.make_mesh``), with GSPMD-propagated (``Auto``) axes. A host
+    with more devices uses the first prod(shape) (the dry-run host
+    exposes 512 placeholder devices; the single-pod mesh uses 256)."""
+    from jax.sharding import AxisType
     n = 1
     for s in shape:
         n *= s
     devs = jax.devices()
     if len(devs) < n:
         raise ValueError(f"need {n} devices, have {len(devs)}")
-    return Mesh(np.asarray(devs[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape),
+                         devices=devs[:n])
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

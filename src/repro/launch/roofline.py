@@ -1,10 +1,13 @@
 """Roofline-term derivation from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), in seconds (v5e hardware constants):
+Three terms per (arch x shape x mesh), in seconds, against the per-chip
+peaks of one row of :data:`PEAKS` (the dry-run's production target is a
+v5e pod, so it uses the v5e row; live cost attribution looks the row up
+by the ``device_kind`` that ran):
 
-  compute    = HLO_FLOPs   / (chips * 197e12 FLOP/s bf16)
-  memory     = HLO_bytes   / (chips * 819e9  B/s HBM)
-  collective = coll_bytes  / (chips * 50e9   B/s per ICI link)
+  compute    = HLO_FLOPs   / (chips * peak FLOP/s bf16)
+  memory     = HLO_bytes   / (chips * peak HBM B/s)
+  collective = coll_bytes  / (chips * peak B/s per ICI link)
 
 cost_analysis() provides FLOPs/bytes. Collective bytes are NOT in
 cost_analysis: we parse the post-SPMD HLO text and sum operand sizes of
@@ -22,9 +25,34 @@ import json
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link (per chip, one direction)
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one accelerator."""
+    flops: float                 # bf16 FLOP/s
+    hbm_bw: float                # HBM bytes/s
+    ici_bw: float                # bytes/s per ICI link, one direction
+    source: str
+
+
+# keyed by jax's ``device.device_kind``; a device that is not here gets no
+# roofline numbers at all, never another chip's
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8 / 4,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "819 GB/s HBM, 1,600 Gbit/s ICI per chip over 4 links"),
+}
+V5E = PEAKS["TPU v5 lite"]
+
+
+def device_peaks(device=None) -> Optional[Peaks]:
+    """The :data:`PEAKS` row of ``device`` (default: the first jax
+    device), or None when its ``device_kind`` is not in the table."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    return PEAKS.get(device.device_kind)
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 0.5, "u4": 0.5, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -99,11 +127,12 @@ class Roofline:
     t_compute: float = 0.0
     t_memory: float = 0.0
     t_collective: float = 0.0
+    peaks: Peaks = V5E
 
     def finalize(self) -> "Roofline":
-        self.t_compute = self.flops_per_dev / PEAK_FLOPS
-        self.t_memory = self.bytes_per_dev / HBM_BW
-        self.t_collective = self.coll_bytes_per_dev / ICI_BW
+        self.t_compute = self.flops_per_dev / self.peaks.flops
+        self.t_memory = self.bytes_per_dev / self.peaks.hbm_bw
+        self.t_collective = self.coll_bytes_per_dev / self.peaks.ici_bw
         return self
 
     @property
@@ -134,8 +163,8 @@ class Roofline:
         """
         if self.roofline_time <= 0:
             return 0.0
-        t_useful_c = (self.model_flops / self.chips) / PEAK_FLOPS
-        t_useful_b = (self.useful_bytes / self.chips) / HBM_BW
+        t_useful_c = (self.model_flops / self.chips) / self.peaks.flops
+        t_useful_b = (self.useful_bytes / self.chips) / self.peaks.hbm_bw
         return max(t_useful_c, t_useful_b) / self.roofline_time
 
     @property
@@ -146,6 +175,7 @@ class Roofline:
 
     def to_dict(self) -> dict:
         d = dataclasses.asdict(self)
+        d["peaks"] = self.peaks.source
         d.update(bottleneck=self.bottleneck,
                  useful_flops_ratio=self.useful_flops_ratio,
                  roofline_time=self.roofline_time,
@@ -187,11 +217,12 @@ def useful_bytes_for(cfg, suite, serve_weights: str = "fp16") -> float:
 
 def from_artifacts(arch: str, shape: str, mesh_name: str, chips: int,
                    cost: Dict, coll: Dict, model_flops: float,
-                   useful_bytes: float = 0.0) -> Roofline:
+                   useful_bytes: float = 0.0,
+                   peaks: Peaks = V5E) -> Roofline:
     return Roofline(
         arch=arch, shape=shape, mesh=mesh_name, chips=chips,
         flops_per_dev=float(cost.get("flops", 0.0)),
         bytes_per_dev=float(cost.get("bytes accessed", 0.0)),
         coll_bytes_per_dev=float(coll.get("total", 0.0)),
         model_flops=model_flops,
-        useful_bytes=useful_bytes).finalize()
+        useful_bytes=useful_bytes, peaks=peaks).finalize()

@@ -5,13 +5,20 @@ them with QMC, and serves batched requests through the engine.
   PYTHONPATH=src python -m repro.launch.serve --arch stablelm-1.6b \
       --reduced --requests 8 --new-tokens 16 --weights qmc
 
+``--no-reduced`` serves the published widths; ``chip_smoke.py`` at the
+repo root drives this module's ``load_model`` / ``build_engine`` /
+``make_requests`` at full StableLM-2-1.6B width on one TPU.
+
 Sharded serving drives the SAME step-builder layer (``serve/steps.py``)
 the engine uses everywhere: ``--data-shards D --model-shards M`` builds a
 (D, M) ``("data", "model")`` mesh, quantizes the weights per TP shard
 (``tp_shards=M`` — the QMC quantize-after-shard deployment format), builds
 the paged step set explicitly, and hands it to ``ServeEngine``. Run under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to demo on a CPU
-host.
+host. The mesh follows the physical device topology (``jax.make_mesh``).
+
+JAX's persistent compilation cache goes where ``JAX_COMPILATION_CACHE_DIR``
+says, else to ``<repo>/.jax_cache`` (``launch/compile_cache.py``).
 
 Observability: ``--trace-out t.json`` writes a Perfetto-loadable Chrome
 trace of the run (round phase spans + request lifecycle instants, see
@@ -31,6 +38,7 @@ from repro.configs import get_config, reduced_config
 from repro.core.qconfig import QMCConfig
 from repro.core.serving_quant import quantize_for_serving
 from repro.launch import mesh as meshlib
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.model import init_params
 from repro.obs import costs as obs_costs
 from repro.obs import metrics as obs_metrics
@@ -41,7 +49,7 @@ from repro.serve.paged_kv import pages_for
 from repro.serve.sampling import SamplingParams
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
     ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
@@ -81,7 +89,7 @@ def main():
                     help="attend through the ragged Pallas page-table "
                          "kernel (streams live pages only, for decode "
                          "tokens and prefill chunks alike; "
-                         "interpret-mode off TPU)")
+                         "interpret-mode on the CPU backend)")
     ap.add_argument("--chunked-prefill", action="store_true",
                     help="split prompts into fixed-size chunks that "
                          "co-schedule with decode lanes in the same jit "
@@ -115,12 +123,12 @@ def main():
                          "print the per-step roofline attribution table "
                          "+ modeled memory-system cost after the run "
                          "(obs/costs.py; makes step calls synchronous)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    if args.cost_report:
-        # before any step set is built, so every wrapper captures
-        obs_costs.enable_capture()
 
+def load_model(args):
+    """(cfg, params): random-init weights from ``--seed``, quantized to
+    the QMC serving format (per TP shard) with ``--weights qmc``."""
     cfg = reduced_config(args.arch) if args.reduced else get_config(
         args.arch)
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
@@ -129,9 +137,30 @@ def main():
         params = quantize_for_serving(
             params, QMCConfig(rho=args.rho, granularity="subtile"),
             tp_shards=args.model_shards, min_dim=64)
+        params = jax.block_until_ready(params)
         print(f"[serve] QMC PTQ in {time.monotonic()-t0:.1f}s "
               f"(tp_shards={args.model_shards})")
+    return cfg, params
 
+
+def make_requests(args, cfg) -> list:
+    """``--requests`` prompts of ``--sys-prompt-len + --prompt-len``
+    random tokens drawn from ``--seed`` (the same list on every call)."""
+    rng = np.random.default_rng(args.seed)
+    sys_prompt = rng.integers(2, cfg.vocab, size=args.sys_prompt_len)
+    return [Request(uid=i,
+                    prompt=np.concatenate(
+                        [sys_prompt,
+                         rng.integers(2, cfg.vocab, size=args.prompt_len)]
+                    ).astype(np.int32),
+                    max_new_tokens=args.new_tokens)
+            for i in range(args.requests)]
+
+
+def build_engine(args, cfg, params, tracer=None) -> ServeEngine:
+    """The serving engine for ``args``: the (data, model) mesh when
+    either axis exceeds 1, the paged step set built through the shared
+    builder layer, and the sampling/loop options."""
     mesh = None
     if args.data_shards * args.model_shards > 1:
         mesh = meshlib.make_mesh((args.data_shards, args.model_shards),
@@ -139,16 +168,6 @@ def main():
         print(f"[serve] mesh data={args.data_shards} "
               f"model={args.model_shards} over "
               f"{args.data_shards * args.model_shards} devices")
-
-    rng = np.random.default_rng(args.seed)
-    sys_prompt = rng.integers(2, cfg.vocab, size=args.sys_prompt_len)
-    reqs = [Request(uid=i,
-                    prompt=np.concatenate(
-                        [sys_prompt,
-                         rng.integers(2, cfg.vocab, size=args.prompt_len)]
-                    ).astype(np.int32),
-                    max_new_tokens=args.new_tokens)
-            for i in range(args.requests)]
 
     # build the step set through the shared builder layer (exactly what
     # the engine would build itself — passing it in pins the contract)
@@ -167,22 +186,35 @@ def main():
         n_pages=n_pages, max_slots=args.slots,
         max_pages_per_seq=mpps, chunk=chunk,
         paged_attention=args.paged_attention)
+    sp = SamplingParams(temperature=args.temperature, top_k=args.top_k,
+                        top_p=args.top_p, seed=args.seed,
+                        logprobs=args.logprobs)
+    return ServeEngine(cfg, params, slots=args.slots, max_len=max_len,
+                       page_size=args.page_size, mesh=mesh,
+                       step_set=step_set, chunk_tokens=chunk,
+                       prefix_cache=args.prefix_cache,
+                       paged_attention=args.paged_attention,
+                       sampling=sp, speculative_k=args.speculative,
+                       pipelined=args.pipelined, tracer=tracer)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    configure_compile_cache()
+    if args.cost_report:
+        # before any step set is built, so every wrapper captures
+        obs_costs.enable_capture()
+
+    cfg, params = load_model(args)
+    reqs = make_requests(args, cfg)
     tracer = None
     if args.trace_out:
         tracer = obs_trace.Tracer(enabled=True)
         # install as the process default so deep call sites (scheduler,
         # prefix cache, jit wrappers) emit into the same trace
         obs_trace.set_tracer(tracer)
-    sp = SamplingParams(temperature=args.temperature, top_k=args.top_k,
-                        top_p=args.top_p, seed=args.seed,
-                        logprobs=args.logprobs)
-    eng = ServeEngine(cfg, params, slots=args.slots, max_len=max_len,
-                      page_size=args.page_size, mesh=mesh,
-                      step_set=step_set, chunk_tokens=chunk,
-                      prefix_cache=args.prefix_cache,
-                      paged_attention=args.paged_attention,
-                      sampling=sp, speculative_k=args.speculative,
-                      pipelined=args.pipelined, tracer=tracer)
+    eng = build_engine(args, cfg, params, tracer)
+    chunk = eng.chunk
     if args.profile:
         with jax.profiler.trace(args.profile):
             eng.run(reqs)

@@ -1,12 +1,10 @@
-"""jax-version compatibility over AOT compilation artifacts.
+"""Cost counters of AOT compilation artifacts.
 
-``Compiled.cost_analysis()`` changed shape across jax releases: newer
-versions return a flat ``{counter: value}`` dict, older ones a
-one-element list of such dicts, and some backends return ``None`` (or
-raise) when the compiler exposes no cost model at all. Every consumer in
-this repo — the dry-run roofline (``launch/dryrun.py``) and the live
-serving cost-attribution layer (``obs/costs.py``) — parses through THIS
-module so the normalization logic exists exactly once.
+``Compiled.cost_analysis()`` returns a flat ``{counter: value}`` dict,
+or ``None`` (or raises) when the backend exposes no cost model at all.
+Every consumer in this repo — the dry-run roofline (``launch/dryrun.py``)
+and the live serving cost-attribution layer (``obs/costs.py``) — parses
+through THIS module so the normalization logic exists exactly once.
 """
 from __future__ import annotations
 
@@ -16,17 +14,15 @@ from typing import Dict, Tuple
 def cost_analysis_dict(compiled) -> Dict:
     """Normalized ``compiled.cost_analysis()``: always a flat dict.
 
-    Newer jax returns a flat dict, older a one-element list of dicts;
-    ``None``, an empty list, or a raising backend all collapse to ``{}``
-    — callers degrade to zero-cost attribution, never crash.
+    ``None`` or a raising backend collapses to ``{}`` — callers decide
+    whether a missing cost model is an error (``obs/costs.py`` raises on
+    a TPU).
     """
     try:
-        ca = compiled.cost_analysis() or {}
+        ca = compiled.cost_analysis()
     except Exception:
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    return dict(ca or {})
 
 
 def flops_bytes(compiled) -> Tuple[float, float]:

@@ -7,13 +7,16 @@ This module closes that gap three ways, per engine run:
   1. **Capture** — with :func:`enable_capture` on, every serving jit
      wrapped in ``serve/steps.py:TracedJit`` AOT-lowers each new call
      shape once and records ``cost_analysis()`` FLOPs / bytes-accessed
-     per call (the ``launch/xla_compat.py`` shim; backends without a
-     cost model degrade to zeros, never raise).
+     per call (the ``launch/xla_compat.py`` shim). On a TPU a failed
+     capture raises; off it (the CPU tests) it degrades to zeros.
   2. **Attribute** — :func:`attribute` diffs the step set's per-shape
      call/wall tables across a run and scores each (fn, shape) against
      its roofline bound (``launch/roofline.py``): measured wall seconds
-     vs ``calls * max(flops/PEAK_FLOPS, bytes/HBM_BW)``, plus arithmetic
-     intensity. The drift ratio (measured / roofline) is the
+     vs ``calls * max(flops/peak FLOP/s, bytes/peak HBM B/s)`` with the
+     peaks of the device that ran (``roofline.device_peaks``), plus
+     arithmetic intensity. A device that is not in the peak table gets
+     no roofline columns; capture on an accelerator that is not in it
+     refuses to start. The drift ratio (measured / roofline) is the
      model-vs-measured health signal — a QMC step 5x over its roofline
      is kernel overhead, not bandwidth.
   3. **Model** — the same run's ``EngineStats`` page/token counters feed
@@ -61,8 +64,20 @@ _CAPTURE = False
 
 
 def enable_capture(on: bool = True) -> bool:
-    """Turn per-call cost capture on/off; returns the previous state."""
+    """Turn per-call cost capture on/off; returns the previous state.
+
+    Raises ValueError when turning it on for an accelerator whose
+    ``device_kind`` has no row in ``launch.roofline.PEAKS``: its roofline
+    columns would have no honest bound to compare against."""
     global _CAPTURE
+    if on:
+        import jax
+        dev = jax.devices()[0]
+        if dev.platform != "cpu" and rl.device_peaks(dev) is None:
+            raise ValueError(
+                f"no peak table row for device kind {dev.device_kind!r} "
+                f"(launch/roofline.py PEAKS); add its published peaks "
+                f"before capturing costs on it")
     prev, _CAPTURE = _CAPTURE, bool(on)
     return prev
 
@@ -74,18 +89,25 @@ def capture_enabled() -> bool:
 def capture_costs(fn, args, kw) -> Dict[str, float]:
     """AOT-lower one call shape and read its cost analysis.
 
-    ``{"flops": f, "bytes": b}`` per invocation (per device); any
-    failure — a non-jit callable, a backend without ``lower``, an empty
-    cost model — degrades to zeros. Attribution then reports measured
-    wall time with the roofline columns zeroed and the drift gauge
-    suppressed; it never raises into the serving path.
+    ``{"flops": f, "bytes": b}`` per invocation (per device). On a TPU
+    any failure — a callable without ``lower``, a compile error, an
+    empty cost model — raises. Off the TPU it degrades to zeros:
+    attribution then reports measured wall time with no roofline
+    columns and the drift gauge suppressed.
     """
+    import jax
+    on_tpu = jax.default_backend() == "tpu"
     try:
         compiled = fn.lower(*args, **kw).compile()
         flops, nbytes = xla_compat.flops_bytes(compiled)
-        return {"flops": flops, "bytes": nbytes}
     except Exception:
+        if on_tpu:
+            raise
         return {"flops": 0.0, "bytes": 0.0}
+    if on_tpu and not (flops or nbytes):
+        raise RuntimeError("TPU cost_analysis() reported no FLOPs and no "
+                           "bytes for a serving step")
+    return {"flops": flops, "bytes": nbytes}
 
 
 # ---------------------------------------------------------------------------
@@ -100,6 +122,9 @@ class FnCost:
     wall_s: float                    # measured (synchronous) wall seconds
     flops_per_call: float
     bytes_per_call: float
+    # peaks of the device the calls ran on; None (a device not in the
+    # peak table, e.g. the CPU) leaves the row without roofline columns
+    peaks: Optional[rl.Peaks] = None
 
     @property
     def label(self) -> str:
@@ -109,16 +134,25 @@ class FnCost:
     def captured(self) -> bool:
         return self.flops_per_call > 0 or self.bytes_per_call > 0
 
+    @property
+    def has_roofline(self) -> bool:
+        """Captured costs AND a known device: the roofline columns
+        (bound, drift, fraction) mean something."""
+        return self.captured and self.peaks is not None
+
     def roofline(self) -> rl.Roofline:
         return rl.from_artifacts(
             self.fn, self.key, "-", 1,
             {"flops": self.flops_per_call,
              "bytes accessed": self.bytes_per_call},
-            {}, model_flops=0.0)
+            {}, model_flops=0.0, peaks=self.peaks)
 
     @property
     def roofline_s(self) -> float:
-        """Bound time for all calls: max(compute, memory) per call."""
+        """Bound time for all calls: max(compute, memory) per call (0
+        without a peak table row)."""
+        if self.peaks is None:
+            return 0.0
         return self.calls * self.roofline().roofline_time
 
     @property
@@ -140,13 +174,15 @@ class FnCost:
                 if self.bytes_per_call > 0 else 0.0)
 
     def to_dict(self) -> dict:
-        return {"fn": self.fn, "key": self.key, "calls": self.calls,
-                "wall_s": self.wall_s,
-                "flops_per_call": self.flops_per_call,
-                "bytes_per_call": self.bytes_per_call,
-                "roofline_s": self.roofline_s, "drift": self.drift,
-                "roofline_fraction": self.roofline_fraction,
-                "arithmetic_intensity": self.arithmetic_intensity}
+        d = {"fn": self.fn, "key": self.key, "calls": self.calls,
+             "wall_s": self.wall_s,
+             "flops_per_call": self.flops_per_call,
+             "bytes_per_call": self.bytes_per_call,
+             "arithmetic_intensity": self.arithmetic_intensity}
+        if self.peaks is not None:
+            d.update(roofline_s=self.roofline_s, drift=self.drift,
+                     roofline_fraction=self.roofline_fraction)
+        return d
 
 
 def _traced_members(step_set):
@@ -169,9 +205,11 @@ def snapshot(step_set) -> Dict[Tuple[str, str], Tuple[int, float]]:
     return out
 
 
-def collect(step_set, baseline=None) -> List[FnCost]:
+def collect(step_set, baseline=None,
+            peaks: Optional[rl.Peaks] = None) -> List[FnCost]:
     """Attribution rows for a step set, minus an optional prior
-    :func:`snapshot` (so warm engines report only their own run)."""
+    :func:`snapshot` (so warm engines report only their own run), scored
+    against ``peaks`` (None: no roofline columns)."""
     baseline = baseline or {}
     rows = []
     for fn in _traced_members(step_set):
@@ -185,7 +223,8 @@ def collect(step_set, baseline=None) -> List[FnCost]:
                 fn=fn.name, key=key, calls=calls,
                 wall_s=fn.seconds_by_key.get(key, 0.0) - s0,
                 flops_per_call=float(cost.get("flops", 0.0)),
-                bytes_per_call=float(cost.get("bytes", 0.0))))
+                bytes_per_call=float(cost.get("bytes", 0.0)),
+                peaks=peaks))
     rows.sort(key=lambda r: -r.wall_s)
     return rows
 
@@ -306,11 +345,12 @@ class CostReport:
         lines = [f"{'fn/shape':14s} {'calls':>6s} {'wall_s':>9s} "
                  f"{'roofline_s':>10s} {'drift':>7s} {'ai':>7s}"]
         for r in self.fns:
-            lines.append(
-                f"{r.label:14s} {r.calls:6d} {r.wall_s:9.4f} "
-                f"{r.roofline_s:10.6f} "
-                f"{(f'{r.drift:7.1f}' if r.captured else '      -')} "
-                f"{(f'{r.arithmetic_intensity:7.2f}' if r.captured else '      -')}")
+            roof = (f"{r.roofline_s:10.6f} {r.drift:7.1f}"
+                    if r.has_roofline else f"{'-':>10s} {'-':>7s}")
+            ai = (f"{r.arithmetic_intensity:7.2f}" if r.captured
+                  else f"{'-':>7s}")
+            lines.append(f"{r.label:14s} {r.calls:6d} {r.wall_s:9.4f} "
+                         f"{roof} {ai}")
         m = self.modeled
         if m and not m.get("degenerate"):
             lines.append(
@@ -326,12 +366,13 @@ def attribute(step_set, stats, *, cfg, params=None,
               method: Optional[str] = None, page: int,
               kv_dtype_bits: int = 16, baseline=None, qmc=None,
               sys_cfg: Optional[MemSystemConfig] = None) -> CostReport:
-    """Assemble a run's :class:`CostReport` from its step set + stats."""
+    """Assemble a run's :class:`CostReport` from its step set + stats,
+    scored against the peaks of the device that ran."""
     if method is None:
         method = detect_weights_method(params) if params is not None \
             else "fp16"
     return CostReport(
-        fns=collect(step_set, baseline),
+        fns=collect(step_set, baseline, rl.device_peaks()),
         modeled=modeled_memsys(cfg, stats, method=method, page=page,
                                kv_dtype_bits=kv_dtype_bits, qmc=qmc,
                                sys_cfg=sys_cfg),
@@ -345,7 +386,8 @@ def attribute(step_set, stats, *, cfg, params=None,
 def flush_metrics(reg, report: CostReport) -> None:
     """Fold a report into a metrics registry per the ``serve_cost_*``
     contract (``obs/metrics.py``). The drift gauge is only set for rows
-    whose capture succeeded — a backend without a cost model suppresses
+    with a roofline (captured costs on a device in the peak table) — a
+    backend without a cost model, or a device without peaks, suppresses
     it rather than reporting drift=0 as if the step hit its roofline."""
     flops = reg.counter("serve_cost_flops_total",
                         "captured XLA FLOPs executed, per fn/shape",
@@ -359,7 +401,7 @@ def flush_metrics(reg, report: CostReport) -> None:
     for r in report.fns:
         flops.inc(r.flops_per_call * r.calls, fn=r.label)
         nbytes.inc(r.bytes_per_call * r.calls, fn=r.label)
-        if r.captured:
+        if r.has_roofline:
             drift.set(r.drift, fn=r.label)
     m = report.modeled
     if m and not m.get("degenerate"):

@@ -36,8 +36,9 @@ only when cost capture ran — see that module). ``fn`` labels are
   * ``serve_cost_bytes_total{fn}``        — captured XLA bytes accessed
   * ``serve_cost_drift_ratio{fn}``        — gauge: measured wall /
     roofline bound per fn/shape; SUPPRESSED (not set) for rows whose
-    ``cost_analysis()`` capture degraded to zeros, so a backend without
-    a cost model never reports a fake drift of 0
+    ``cost_analysis()`` capture degraded to zeros or whose device has no
+    peak table row (``launch/roofline.py:PEAKS``, e.g. the CPU), so no
+    run reports a fake drift of 0 or one against another chip's peaks
   * ``serve_cost_modeled_bytes_per_token``     — gauge: Eq. (3)/(4)
     modeled memory traffic per emitted token
   * ``serve_cost_modeled_energy_j{system}``    — gauge: modeled

@@ -44,7 +44,7 @@ and dispatch. The contract:
     tokens are the previous step's on-device output, fed straight back
     in (``steps.carry_decode_tokens``) — decode tokens never round-trip
     through host. Readback starts asynchronously at dispatch
-    (``copy_to_host_async`` where available); emission, EOS checks and
+    (``copy_to_host_async``); emission, EOS checks and
     scheduling run at retire, one round behind the in-flight dispatch.
   * **What barriers.** Admission, preemption, prefill grants, and any
     allocation that needs eviction-by-preemption drain the pipeline:
@@ -585,7 +585,11 @@ class ServeEngine:
         if not (copies or resets or pool.tables_dirty):
             return self._arena
         pool.check_tables()
-        tables = jnp.asarray(pool.block_tables)
+        # host buffers rewritten in place after the dispatch (the pool's
+        # tables, the reset mask below) go up as copies (jnp.array): on
+        # the CPU jnp.asarray maps a 64-byte-aligned host buffer without
+        # copying, and the async step could read the rewrite
+        tables = jnp.array(pool.block_tables)
         reset_mask = np.zeros(self.slots, bool)
         for s in resets:
             reset_mask[s] = True
@@ -599,7 +603,7 @@ class ServeEngine:
                 src[i], dst[i] = a, b
             self._arena = self._steps.apply_page_ops(
                 self._arena, jnp.asarray(src), jnp.asarray(dst),
-                tables, jnp.asarray(reset_mask))
+                tables, jnp.array(reset_mask))
             self.stats.page_op_flushes += 1
             reset_mask[:] = False
             first = False
@@ -962,32 +966,30 @@ class ServeEngine:
 
         def readback_async(rec):
             # start the D2H copy at dispatch time so the retire's
-            # gather finds it complete (or at least in flight); arrays
-            # without the API just block in gather instead
+            # gather finds it complete (or at least in flight)
             for arr in (rec["tok_dev"], rec["logp_dev"]):
-                try:
-                    arr.copy_to_host_async()
-                except AttributeError:
-                    pass
+                arr.copy_to_host_async()
 
         def dispatch(toks_in, cache_in, start, n_new, solo_slot=None):
             """The round's ONE async step dispatch — never blocks; the
             sync path gathers inline, the pipelined path one round
             later."""
+            # the sampling lanes are copied (jnp.array): set_lane
+            # rewrites them in place, see _flush_page_ops
             if solo_slot is not None:
                 tok_dev, logp_dev, self._arena = self._steps.solo_step(
                     self._step_params(), toks_in, cache_in,
                     np.int32(solo_slot),
                     jnp.asarray(start[solo_slot:solo_slot + 1]),
                     jnp.asarray(n_new[solo_slot:solo_slot + 1]),
-                    {k: jnp.asarray(v[solo_slot:solo_slot + 1])
+                    {k: jnp.array(v[solo_slot:solo_slot + 1])
                      for k, v in self._samp.items()})
                 self.stats.solo_rounds += 1
             else:
                 tok_dev, logp_dev, self._arena = self._steps.step(
                     self._step_params(), toks_in, cache_in,
                     jnp.asarray(start), jnp.asarray(n_new),
-                    {k: jnp.asarray(v) for k, v in self._samp.items()})
+                    {k: jnp.array(v) for k, v in self._samp.items()})
             return tok_dev, logp_dev
 
         def retire(rec, defer_rec=None):

@@ -93,10 +93,9 @@ class PagedKVPool:
         # these quantify the prefix-cache adopt/COW host overhead):
         # adopt_calls counts page-adoption events (block-table rewrites
         # for cached/dedup'd pages), tables_rebuilds counts device_tables
-        # host→device uploads the content cache could not elide
+        # host→device uploads
         self.adopt_calls = 0
         self.tables_rebuilds = 0
-        self._tbl_cache = None       # (key, device array) — see below
         # set on every block-table mutation, cleared by the engine after
         # it pushes the tables to the device (the fused apply_page_ops
         # flush) — pure decode rounds skip the rebuild entirely
@@ -265,21 +264,15 @@ class PagedKVPool:
     def device_tables(self, n_groups: int) -> jax.Array:
         """Block tables as a device array broadcast over layer groups.
 
-        Cached by table *content* — but only on CPU, where the serving
-        steps disable arena donation (``serve/steps.py``). On accelerator
-        backends the steps donate the arena and the tables ride inside
-        it, so a cached device buffer would be invalidated by the
-        donation the first time it was reused; there the array is rebuilt
-        per call (a few hundred int32s — negligible next to the step)."""
-        key = (n_groups, self.block_tables.tobytes())
-        if self._tbl_cache is not None and self._tbl_cache[0] == key:
-            return self._tbl_cache[1]
+        Built afresh per call (a few hundred int32s — negligible next to
+        the step): the serving steps donate the arena the tables ride
+        in, so a cached device buffer would be dead after its first
+        use. The tables go up as a copy (``jnp.array``): the pool
+        rewrites them in place, and on the CPU ``jnp.asarray`` maps an
+        aligned host buffer without copying."""
         self.tables_rebuilds += 1
-        tbl = jnp.asarray(self.block_tables)
-        dev = jnp.broadcast_to(tbl[None], (n_groups,) + tbl.shape)
-        if jax.default_backend() == "cpu":
-            self._tbl_cache = (key, dev)
-        return dev
+        tbl = jnp.array(self.block_tables)
+        return jnp.broadcast_to(tbl[None], (n_groups,) + tbl.shape)
 
     # ---- device arena --------------------------------------------------
     def init_arena(self):

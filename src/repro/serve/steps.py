@@ -110,9 +110,9 @@ Sharding contract (what shards, what replicates)
     batched step, so they shard with it).
   * **SSM/conv state** — dense per-slot, batch on dp when divisible.
 
-Arena buffers are donated on non-CPU backends (the step, state reset and
-page copy all rewrite the arena in place); the CPU backend cannot donate
-and would warn on every call, so donation is disabled there.
+Arena buffers are donated on every backend (the step, state reset, page
+copy and fused page ops all rewrite the arena in place): the arena passed
+in is dead after the call, and callers keep only the returned one.
 """
 from __future__ import annotations
 
@@ -490,15 +490,6 @@ def arena_struct(cfg: ModelConfig, *, n_pages: int, page: int,
                                     max_pages_per_seq, cache_dtype))
 
 
-def _donate(argnums: Tuple[int, ...]) -> dict:
-    """Arena donation kwargs — disabled on CPU, where XLA cannot alias
-
-    buffers and jax warns on every call."""
-    if jax.default_backend() == "cpu":
-        return {}
-    return {"donate_argnums": argnums}
-
-
 def build_paged_steps(cfg: ModelConfig, mesh=None, params_struct=None, *,
                       page: int, n_pages: int, max_slots: int,
                       max_pages_per_seq: int,
@@ -582,7 +573,7 @@ def build_paged_steps(cfg: ModelConfig, mesh=None, params_struct=None, *,
             "reset_state",
             jax.jit(_reset_state_body(cfg),
                     in_shardings=(a_sh, rep), out_shardings=a_sh,
-                    **_donate((0,))), 1)
+                    donate_argnums=0), 1)
     return PagedServeSteps(
         cfg=cfg, mesh=mesh, page=page, n_pages=n_pages,
         max_slots=max_slots, max_pages_per_seq=max_pages_per_seq,
@@ -593,19 +584,19 @@ def build_paged_steps(cfg: ModelConfig, mesh=None, params_struct=None, *,
             jax.jit(step_fn,
                     in_shardings=(p_sh, tok_sh, a_sh, b_sh, b_sh, samp_sh),
                     out_shardings=(tok_sh, tok_sh, a_sh),
-                    **_donate((2,))), step_shapes,
+                    donate_argnums=2), step_shapes,
             cost_key=_step_cost_key),
         page_copy=TracedJit(
             "page_copy",
             jax.jit(_page_copy_body(cfg),
                     in_shardings=(a_sh, rep, rep),
-                    out_shardings=a_sh, **_donate((0,))), 1),
+                    out_shardings=a_sh, donate_argnums=0), 1),
         reset_state=reset,
         apply_page_ops=TracedJit(
             "apply_page_ops",
             jax.jit(_apply_page_ops_body(cfg),
                     in_shardings=(a_sh, rep, rep, rep, rep),
-                    out_shardings=a_sh, **_donate((0,))), 1),
+                    out_shardings=a_sh, donate_argnums=0), 1),
         solo_step=None)
 
 
@@ -793,11 +784,11 @@ def _single_device_steps(cfg: ModelConfig, page: int, n_pages: int,
     The key is exactly the tuple ``PagedServeSteps.compatible_with``
     checks — a flag (or geometry knob) passed late can never silently
     reuse a jit traced for a different configuration."""
-    step = jax.jit(_step_body(cfg, paged_attention))
-    page_copy = jax.jit(_page_copy_body(cfg))
-    apply_ops = jax.jit(_apply_page_ops_body(cfg))
-    solo = jax.jit(_solo_step_body(cfg, paged_attention))
+    step = jax.jit(_step_body(cfg, paged_attention), donate_argnums=2)
+    page_copy = jax.jit(_page_copy_body(cfg), donate_argnums=0)
+    apply_ops = jax.jit(_apply_page_ops_body(cfg), donate_argnums=0)
+    solo = jax.jit(_solo_step_body(cfg, paged_attention), donate_argnums=2)
     reset = None
     if any(k == "mamba" or k.startswith("hybrid") for k in cfg.pattern):
-        reset = jax.jit(_reset_state_body(cfg))
+        reset = jax.jit(_reset_state_body(cfg), donate_argnums=0)
     return step, page_copy, reset, apply_ops, solo

@@ -73,3 +73,19 @@ def test_ops_dispatch_fallback():
     y = ops.qmm(x, qt, use_pallas=True)   # silently uses ref path
     np.testing.assert_allclose(np.asarray(y), np.asarray(qmm_ref(x, qt)),
                                atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("backend,interpret,want", [
+    ("cpu", None, True), ("cpu", True, True), ("cpu", False, False),
+    ("tpu", None, False), ("tpu", False, False), ("tpu", True, None)])
+def test_resolve_interpret_only_on_cpu(monkeypatch, backend, interpret,
+                                       want):
+    """Interpret mode is chosen only where the backend is the CPU; on any
+    other backend asking for it raises instead of hiding the device."""
+    from repro.kernels import resolve_interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(ValueError):
+            resolve_interpret(interpret)
+    else:
+        assert resolve_interpret(interpret) is want

@@ -19,7 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+from repro.launch.roofline import PEAKS
 from repro.memsys.workload import make_traffic
 from repro.obs import costs as obs_costs
 from repro.obs import metrics as obs_metrics
@@ -28,6 +28,7 @@ from repro.serve.engine import EngineStats, Request, ServeEngine
 from repro.serve.steps import TracedJit
 
 PAGE = 16
+V5E = PEAKS["TPU v5 lite"]
 
 
 @pytest.fixture
@@ -68,6 +69,47 @@ def test_capture_fallback_never_raises(capture):
     assert rows[0].drift == 0.0        # no roofline -> no drift claim
 
 
+class _FakeDevice:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+@pytest.mark.parametrize("platform,kind,raises", [
+    ("cpu", "cpu", False), ("tpu", "TPU v5 lite", False),
+    ("tpu", "TPU v9 imaginary", True), ("gpu", "Some GPU", True)])
+def test_capture_refuses_accelerator_without_peaks(monkeypatch, platform,
+                                                   kind, raises):
+    """Only the CPU or a device kind with a peak-table row may capture:
+    another accelerator would get no honest roofline bound."""
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice(platform, kind)])
+    prev = obs_costs.capture_enabled()
+    try:
+        if raises:
+            with pytest.raises(ValueError):
+                obs_costs.enable_capture()
+            assert obs_costs.capture_enabled() == prev
+        else:
+            obs_costs.enable_capture()
+            assert obs_costs.capture_enabled()
+    finally:
+        obs_costs.enable_capture(prev)
+
+
+def test_device_peaks_by_kind():
+    from repro.launch.roofline import device_peaks
+    assert device_peaks(_FakeDevice("tpu", "TPU v5 lite")) is V5E
+    assert device_peaks(_FakeDevice("cpu", "cpu")) is None
+    assert V5E.flops == 197e12 and V5E.hbm_bw == 819e9
+
+
+def test_capture_failure_raises_on_tpu(monkeypatch):
+    """On a TPU a failed capture raises instead of reading as zero."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(AttributeError):
+        obs_costs.capture_costs(lambda x: x + 1, (1,), {})
+
+
 def test_capture_real_jit_per_shape(capture):
     tj = TracedJit("f", jax.jit(lambda x: x @ x))
     for _ in range(3):
@@ -104,13 +146,13 @@ def test_fncost_roofline_by_hand():
     # one call whose FLOPs take exactly 1s at peak and whose bytes take
     # 0.5s at HBM bandwidth: the bound is the max stream = 1s
     r = obs_costs.FnCost(fn="step", key="C1", calls=2, wall_s=6.0,
-                         flops_per_call=PEAK_FLOPS,
-                         bytes_per_call=HBM_BW * 0.5)
+                         flops_per_call=V5E.flops,
+                         bytes_per_call=V5E.hbm_bw * 0.5, peaks=V5E)
     assert r.roofline_s == pytest.approx(2.0)          # 2 calls x 1s
     assert r.drift == pytest.approx(3.0)               # 6s wall / 2s bound
     assert r.roofline_fraction == pytest.approx(1 / 3)
     assert r.arithmetic_intensity == pytest.approx(
-        PEAK_FLOPS / (HBM_BW * 0.5))
+        V5E.flops / (V5E.hbm_bw * 0.5))
     assert r.captured
     d = r.to_dict()
     assert d["drift"] == pytest.approx(3.0)
@@ -218,7 +260,8 @@ def test_detect_weights_method(serve_cfg, serve_params):
 def test_flush_suppresses_drift_for_uncaptured_rows():
     reg = obs_metrics.Registry()
     rows = [obs_costs.FnCost(fn="step", key="C1", calls=4, wall_s=1.0,
-                             flops_per_call=1e9, bytes_per_call=1e6),
+                             flops_per_call=1e9, bytes_per_call=1e6,
+                             peaks=V5E),
             obs_costs.FnCost(fn="page_copy", key="call", calls=2,
                              wall_s=0.1, flops_per_call=0.0,
                              bytes_per_call=0.0)]
@@ -260,12 +303,14 @@ def test_engine_run_attributes_costs(serve_cfg, serve_params, capture):
     snap = reg.snapshot()
     assert "serve_cost_flops_total" in snap
     assert "serve_cost_modeled_bytes_per_token" in snap
-    # each captured row reports drift; uncaptured rows (CPU backends
-    # without a cost model) suppress it instead of claiming drift=0
+    # each row with a roofline reports drift; rows without one (no cost
+    # model, or a device such as the CPU that has no peak table row)
+    # suppress it instead of claiming drift=0
     drift_fns = {s["labels"]["fn"]
-                 for s in snap["serve_cost_drift_ratio"]["series"]}
+                 for s in snap.get("serve_cost_drift_ratio",
+                                   {"series": []})["series"]}
     for r in rep.fns:
-        assert (r.label in drift_fns) == r.captured
+        assert (r.label in drift_fns) == r.has_roofline
     # pool-pressure counter tracks, one sample per round
     counters = [e for e in trc.events if e["ph"] == "C"]
     pool = [e for e in counters if e["name"] == "pool/pages"]

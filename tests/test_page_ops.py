@@ -201,3 +201,66 @@ def test_pure_decode_rounds_skip_flush(serve_cfg, serve_params):
     s = eng.stats
     assert s.page_op_flushes < s.rounds
     assert s.rounds > 4
+
+
+def test_engine_round_donates_arena(serve_cfg, serve_params):
+    """The serving steps donate the arena on every backend, the CPU
+    included: after a run the K/V pages the engine started from are dead
+    (updated in place) and the engine holds only live buffers."""
+    eng = _engine(serve_cfg, serve_params)
+    eng._ensure_pool()
+    first = [leaf for path, leaf in
+             jax.tree_util.tree_flatten_with_path(eng._arena)[0]
+             if jax.tree_util.keystr(path).endswith("_pages']")]
+    assert first
+    eng.run(_reqs(n=2))
+    assert all(leaf.is_deleted() for leaf in first)
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree_util.tree_leaves(eng._arena))
+
+
+def _aligned_copy(a):
+    """``a`` copied onto a 64-byte boundary, where the CPU client maps a
+    host buffer into a device array without copying it."""
+    raw = np.zeros(a.nbytes + 64, np.uint8)
+    off = -raw.ctypes.data % 64
+    out = raw[off:off + a.nbytes].view(a.dtype).reshape(a.shape)
+    out[...] = a
+    return out
+
+
+class _AlignedNumpy:
+    """numpy with ``zeros`` on a 64-byte boundary."""
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    @staticmethod
+    def zeros(shape, dtype=float):
+        return _aligned_copy(np.zeros(shape, dtype))
+
+
+def test_flush_uploads_copies_of_rewritten_host_buffers(
+        serve_cfg, serve_params, monkeypatch):
+    """The fused flush hands the step copies of the host buffers the
+    engine rewrites right after it (the reset mask, the pool's block
+    tables). An aliased upload lets the asynchronous step read the
+    rewrite: a reused lane then kept its previous request's SSM state."""
+    from repro.serve import engine as engine_mod
+    eng = _engine(serve_cfg, serve_params)
+    pool = eng._ensure_pool()
+    seen = []
+
+    def spy(arena, src, dst, tables, mask):
+        seen.append((tables, mask))
+        return arena
+
+    eng._steps = dataclasses.replace(eng._steps, apply_page_ops=spy)
+    monkeypatch.setattr(engine_mod, "np", _AlignedNumpy())
+    pool.block_tables = _aligned_copy(pool.block_tables)
+    eng._pending_resets.append(1)
+    eng._flush_page_ops(pool)
+    pool.block_tables[0, 0] = 7
+    (tables, mask), = seen
+    assert np.asarray(mask).tolist() == [False, True, False, False]
+    assert not np.asarray(tables).any()
