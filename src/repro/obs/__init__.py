@@ -4,13 +4,15 @@ Three host-side modules the serving stack records itself through:
 
   * ``obs.metrics`` — counters / gauges / fixed-log-bucket histograms
     with labels, behind a get-or-create :class:`~repro.obs.metrics.
-    Registry`; snapshot-to-JSON and Prometheus text exposition. The
-    metric naming contract lives in its module docstring.
+    Registry`; snapshot to JSON. The metric naming contract lives in
+    its module docstring.
   * ``obs.trace`` — a span :class:`~repro.obs.trace.Tracer` (context-
     manager API, near-zero overhead when disabled, instant events for
-    point occurrences) exporting Chrome trace-event JSON loadable in
-    Perfetto. The span/event naming contract lives in its module
-    docstring.
+    point occurrences, collector spans) exporting Chrome trace-event
+    JSON loadable in Perfetto; each span it records is also a
+    ``jax.profiler.TraceAnnotation``, so a profiler capture shows it on
+    the device trace's clock. The span/event naming contract lives in
+    its module docstring.
   * ``obs.costs`` — per-step cost attribution: opt-in capture of XLA
     ``cost_analysis()`` FLOPs/bytes per serving-jit call shape, roofline
     drift (measured wall vs bound), and the Eq. (3)/(4) modeled memory

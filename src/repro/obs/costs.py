@@ -26,8 +26,7 @@ This module closes that gap three ways, per engine run:
      and per token for the weight format it actually served.
 
 Exports land on the existing obs surfaces via :func:`flush_metrics`
-(``serve_cost_*`` instruments per the ``obs/metrics.py`` contract) and
-the ``cost/<fn>`` Perfetto counter tracks TracedJit emits per call.
+(``serve_cost_*`` instruments per the ``obs/metrics.py`` contract).
 Wired end to end by ``launch/serve.py --cost-report`` and the
 ``cost_attribution`` section of ``benchmarks/serving.py``.
 

@@ -21,8 +21,10 @@ seconds. The instruments the serving stack registers today:
     trips: ``adopt`` | ``page_copy`` | ``tables_rebuild`` | ``cow`` |
     ``cache_evict``
   * ``serve_phase_seconds{phase}``        — histogram of per-round phase
-    wall time, one label value per span name in ``obs/trace.py``'s
-    contract (``round/admit`` ... ``round/emit``)
+    wall time, one label value per round phase in ``obs/trace.py``'s
+    contract (``round/admit`` ... ``round/emit``, ``round/dispatch``,
+    ``round/retire``); the nested ``round/step/*`` parts and
+    ``round/gc`` are not observed, so the labels never double count
   * ``serve_jit_compiles_total{fn}``      — traced-jit cache growth per
     step function (``step`` / ``page_copy`` / ``reset_state``)
   * ``serve_jit_retraces_unexpected_total{fn}`` — compiles beyond a step
@@ -46,14 +48,12 @@ only when cost capture ran — see that module). ``fn`` labels are
   * ``serve_cost_modeled_latency_s{system}``   — gauge: modeled
     per-round latency, same label values
 
-Snapshots serialize two ways: :meth:`Registry.snapshot` (JSON-able dict,
-written by ``--metrics-out``) and :meth:`Registry.to_prometheus` (text
-exposition format, scrapeable once an HTTP front door exists).
+Snapshots serialize through :meth:`Registry.snapshot` (JSON-able dict,
+written by ``--metrics-out``).
 """
 from __future__ import annotations
 
 import json
-import math
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -232,64 +232,6 @@ class Registry:
     def write_json(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.snapshot(), f, indent=2)
-
-    def to_prometheus(self) -> str:
-        """Text exposition format (one scrape body)."""
-        lines = []
-        for name, m in sorted(self._metrics.items()):
-            kind = {"Counter": "counter", "Gauge": "gauge",
-                    "Histogram": "histogram"}[type(m).__name__]
-            if m.help:
-                lines.append(f"# HELP {name} {_esc_help(m.help)}")
-            lines.append(f"# TYPE {name} {kind}")
-            if isinstance(m, Histogram):
-                for key, row in sorted(m._series.items()):
-                    cum = 0
-                    for b, c in zip(m.buckets, row[0]):
-                        cum += c
-                        lines.append(
-                            f"{name}_bucket"
-                            f"{_labels(m.label_names, key, le=_fmt(b))}"
-                            f" {cum}")
-                    cum += row[0][-1]
-                    lines.append(
-                        f"{name}_bucket"
-                        f"{_labels(m.label_names, key, le='+Inf')} {cum}")
-                    lines.append(
-                        f"{name}_sum{_labels(m.label_names, key)}"
-                        f" {_fmt(row[1])}")
-                    lines.append(
-                        f"{name}_count{_labels(m.label_names, key)}"
-                        f" {row[2]}")
-            else:
-                for key, v in sorted(m._values.items()):
-                    lines.append(
-                        f"{name}{_labels(m.label_names, key)} {_fmt(v)}")
-        return "\n".join(lines) + "\n"
-
-
-def _fmt(v: float) -> str:
-    if v == math.inf:
-        return "+Inf"
-    if isinstance(v, float) and v.is_integer() and abs(v) < 1e15:
-        return str(int(v))
-    return repr(v)
-
-
-def _esc_help(s: str) -> str:
-    return s.replace("\\", r"\\").replace("\n", r"\n")
-
-
-def _esc_label(s: str) -> str:
-    return s.replace("\\", r"\\").replace('"', r'\"').replace("\n", r"\n")
-
-
-def _labels(names: Sequence[str], values: Sequence[str], **extra) -> str:
-    pairs = [(n, v) for n, v in zip(names, values)] + list(extra.items())
-    if not pairs:
-        return ""
-    body = ",".join(f'{n}="{_esc_label(str(v))}"' for n, v in pairs)
-    return "{" + body + "}"
 
 
 # ---------------------------------------------------------------------------

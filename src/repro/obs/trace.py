@@ -5,13 +5,27 @@ complete ("X") duration events plus instant ("i") point events, in the
 Chrome ``traceEvents`` format — load the exported file in Perfetto
 (https://ui.perfetto.dev) or ``chrome://tracing``. Timestamps are
 microseconds from the tracer's construction (``time.perf_counter``
-based), one track per Python thread. Pure host-side: tracing a round
-costs a handful of ``perf_counter`` calls and dict appends; a *disabled*
-tracer costs one branch per call site (the engine's acceptance bar is
-< 2 % tokens/s overhead with tracing off).
+based), one track per Python thread.
+
+Every span an enabled tracer records also opens a
+``jax.profiler.TraceAnnotation`` of the same name for its duration, so
+a ``jax.profiler`` capture taken around the run holds the engine's spans
+on its host plane, on the device events' clock (jax is imported on the
+first traced span; this module imports without it). Pure host-side
+otherwise: tracing a round costs a handful of ``perf_counter`` calls,
+annotation enters/exits and dict appends; a *disabled* tracer costs one
+branch per call site (the engine's acceptance bar is < 2 % tokens/s
+overhead with tracing off).
 
 Span / event naming contract (what later PRs must follow)
 ---------------------------------------------------------
+Engine rounds — ``round``: one complete event per pass of the round
+loop (``serve/engine.py``), args ``lanes`` / ``prefill_lanes`` /
+``decode_lanes`` / ``emitted`` and the step's ``c_len``, ``positions``
+(rows x ``c_len`` computed) and ``useful`` (sum of ``n_new``) on the
+passes that launched a step; a pass that only admits, idles or drains
+the pipeline carries no args.
+
 Engine round phases — complete events, one per round, non-overlapping
 and strictly inside their round's wall window, emitted by
 ``serve/engine.py``:
@@ -24,10 +38,10 @@ and strictly inside their round's wall window, emitted by
   * ``round/host_prep``    — building the step's host arrays (tokens /
     start / n_new), gather-work accounting and ``install_tables``
     (block-table validation + host→device upload).
-  * ``round/device_step``  — the jitted unified step + argmax,
-    ``block_until_ready`` + device→host logits transfer included; on a
-    cold geometry this span absorbs the jit compile (see ``jit/compile``
-    instants).
+  * ``round/device_step``  — the whole synchronous step: its four
+    ``round/step/*`` parts below (token selection runs inside the jit);
+    on a cold geometry this span absorbs the jit compile (see
+    ``jit/compile`` instants).
   * ``round/emit``         — token emission, stats, streaming callbacks,
     publish/finish/requeue bookkeeping.
 
@@ -45,6 +59,27 @@ relax the window rule for one of them:
     NEXT round's wall window — the one sanctioned exception to the
     "strictly inside their round" rule above; synchronous engines never
     emit these two spans and keep the original contract bit-for-bit.
+
+Step parts — nested inside the phase that runs the step
+(``round/device_step``; ``round/dispatch`` holds the first two and
+``round/retire`` the last two on the pipelined path), in this order and
+tiling ``round/device_step``. Their seconds accumulate in
+``EngineStats.step_part_seconds``, apart from the phases':
+
+  * ``round/step/upload``   — every device input of the step: tokens
+    (or the pipelined device-token carry), ``start``, ``n_new``, the
+    sampling-lane copies, and the exec-weight tree.
+  * ``round/step/launch``   — the jitted ``step`` / ``solo_step`` call
+    until it returns, then the enqueue of its two outputs' device→host
+    copies (args: ``c_len``, ``rows``, ``solo``).
+  * ``round/step/wait``     — ``block_until_ready`` on its outputs.
+  * ``round/step/readback`` — finishing those copies of the selected
+    tokens and logprobs into full-width host arrays.
+
+Collector — ``round/gc``: one complete event per run of Python's cyclic
+collector while an enabled tracer's :meth:`Tracer.collector_spans` is
+open (``ServeEngine.run`` opens it); args ``generation``, ``collected``.
+It nests inside whatever span was running when the collector started.
 
 Request lifecycle — instant events with ``uid`` (and ``slot``) args,
 emitted by ``serve/engine.py``:
@@ -80,17 +115,27 @@ Counter tracks — "C" events rendering as value lanes on the timeline:
     ``live`` / ``free`` — pool pressure next to the phase spans.
   * ``sched/queue`` — per round (``serve/engine.py``):
     ``prefill_pending`` admission-queue depth.
-  * ``cost/<fn>``   — per traced-jit call when ``obs.costs`` capture is
-    on (``serve/steps.py``): cumulative captured ``flops`` / ``bytes``
-    of that step function, e.g. ``cost/step``.
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
 import time
 from typing import List, Optional
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` of ``name`` (not yet entered);
+    jax is imported on the first call."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
 
 
 class _NullSpan:
@@ -109,18 +154,50 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "args", "t0")
+    __slots__ = ("tracer", "name", "args", "t0", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.tracer, self.name, self.args = tracer, name, args
 
     def __enter__(self):
+        self.ann = _annotation(self.name)
+        self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
         self.tracer._complete(self.name, self.t0, t1 - self.t0, self.args)
+        return False
+
+
+class _CollectorSpans:
+    """Records each run of Python's cyclic collector as a ``round/gc``
+    span while open: a ``gc.callbacks`` hook, removed on exit."""
+
+    __slots__ = ("tracer", "hook", "span")
+
+    def __init__(self, tracer: "Tracer"):
+        self.tracer, self.span = tracer, None
+        self.hook = self._on_gc
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self.span = self.tracer.span("round/gc",
+                                         generation=info["generation"])
+            self.span.__enter__()
+        elif self.span is not None:
+            span, self.span = self.span, None
+            span.args["collected"] = info["collected"]
+            span.__exit__(None, None, None)
+
+    def __enter__(self):
+        gc.callbacks.append(self.hook)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self.hook)
         return False
 
 
@@ -132,25 +209,35 @@ class Tracer:
     shared objects — instrument call sites unconditionally and let the
     flag decide. All recording is in-memory (a list of small dicts);
     :meth:`export` writes the ``{"traceEvents": [...]}`` JSON object.
-    Appends are guarded by a lock only on the shared event list; the
-    timestamp math is per-call-site."""
+    Appends are guarded by a lock only on the shared event list (a
+    re-entrant one: a collection that starts inside an append records
+    its own span); the timestamp math is per-call-site."""
 
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
         self.events: List[dict] = []
         self._t0 = time.perf_counter()
         self._pid = os.getpid()
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
 
     # ---- recording -----------------------------------------------------
     def _ts(self, t: float) -> float:
         return (t - self._t0) * 1e6
 
     def span(self, name: str, **args):
-        """Context manager timing a phase; records one "X" event."""
+        """Context manager timing a phase; records one "X" event and
+        holds a profiler annotation of the same name while open."""
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, args)
+
+    def collector_spans(self):
+        """Context manager: while open, each collection of Python's
+        cyclic collector is recorded as a ``round/gc`` span. A disabled
+        tracer installs nothing."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return _CollectorSpans(self)
 
     def _complete(self, name: str, t0: float, dur_s: float,
                   args: dict) -> None:
@@ -162,10 +249,19 @@ class Tracer:
         with self._lock:
             self.events.append(ev)
 
+    def annotation(self, name: str):
+        """A profiler annotation of ``name`` (enter it) for a span the
+        caller times itself and records with :meth:`complete`; a no-op
+        on a disabled tracer."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return _annotation(name)
+
     def complete(self, name: str, t0: float, dur_s: float,
                  **args) -> None:
         """Record an already-measured span (``t0`` in perf_counter
-        seconds) — for call sites that time phases themselves."""
+        seconds). A live span timed this way holds :meth:`annotation`
+        while it runs; else time it with :meth:`span`."""
         if self.enabled:
             self._complete(name, t0, dur_s, args)
 
@@ -205,16 +301,6 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self.events.clear()
-
-    # ---- analysis helpers (bench / tests) ------------------------------
-    def phase_totals(self) -> dict:
-        """Summed "X"-event duration per span name, in seconds."""
-        out: dict = {}
-        for ev in self.events:
-            if ev["ph"] == "X":
-                out[ev["name"]] = out.get(ev["name"], 0.0) \
-                    + ev["dur"] * 1e-6
-        return out
 
 
 # ---------------------------------------------------------------------------

@@ -195,6 +195,17 @@ class EngineStats:
     # vs compile share without parsing a trace file
     phase_seconds: Dict[str, float] = dataclasses.field(
         default_factory=dict)
+    # cumulative wall seconds per part of the step (round/step/upload,
+    # launch, wait, readback per the obs/trace.py contract), nested in
+    # the phase that runs the step: kept apart from phase_seconds so
+    # host_seconds() / device_seconds() never count them twice
+    step_part_seconds: Dict[str, float] = dataclasses.field(
+        default_factory=dict)
+    # B x C positions of every launched step (rows x c_len: one row for
+    # the solo step, every slot otherwise) and the sum of n_new over its
+    # live lanes: 1 - useful / computed is the padded share
+    positions_computed: int = 0
+    positions_useful: int = 0
     # per-request emission timestamps (time.monotonic), keyed by uid —
     # the source of truth for inter-token latency; a preempted request's
     # discarded emissions are dropped with its tokens
@@ -288,28 +299,57 @@ def _finished(req: Request, pos: int, max_len: int) -> bool:
 
 
 class _PhaseSpan:
-    """Times one round phase: accumulates into ``EngineStats.
-    phase_seconds``, observes the ``serve_phase_seconds{phase}``
-    histogram, and (when tracing is on) records the span on the tracer.
-    A plain class CM so the round loop pays two ``perf_counter`` calls
-    per phase, nothing more, with tracing disabled."""
+    """Times one round phase or step part: accumulates into ``seconds``
+    (``EngineStats.phase_seconds`` or ``step_part_seconds``) and, for a
+    phase, observes the ``serve_phase_seconds{phase}`` histogram. A plain
+    class CM so the round loop pays two ``perf_counter`` calls per site,
+    nothing more, with tracing disabled. ``t0`` starts the span at an
+    earlier reading (the previous part's ``t1``, the enclosing phase's
+    ``t0``), so consecutive parts tile with no gap between them."""
 
-    __slots__ = ("name", "tracer", "hist", "stats", "t0")
+    __slots__ = ("name", "seconds", "hist", "t0", "t1")
 
-    def __init__(self, name, tracer, hist, stats):
-        self.name, self.tracer, self.hist = name, tracer, hist
-        self.stats = stats
+    def __init__(self, name, seconds, hist=None, tracer=None, args=None,
+                 t0=None):
+        self.name, self.seconds, self.hist = name, seconds, hist
+        self.t0 = t0
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
+        if self.t0 is None:
+            self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self.t0
-        ph = self.stats.phase_seconds
-        ph[self.name] = ph.get(self.name, 0.0) + dt
-        self.hist.observe(dt, phase=self.name)
-        self.tracer.complete(self.name, self.t0, dt)
+        self.t1 = time.perf_counter()
+        dt = self.t1 - self.t0
+        self.seconds[self.name] = self.seconds.get(self.name, 0.0) + dt
+        if self.hist is not None:
+            self.hist.observe(dt, phase=self.name)
+        return False
+
+
+class _TracedPhaseSpan(_PhaseSpan):
+    """The same, recorded on an enabled tracer from the same two clock
+    readings, inside a profiler annotation of the same name: the engine
+    picks this class once per run when tracing is on."""
+
+    __slots__ = ("tracer", "args", "ann")
+
+    def __init__(self, name, seconds, hist=None, tracer=None, args=None,
+                 t0=None):
+        super().__init__(name, seconds, hist, t0=t0)
+        self.tracer, self.args = tracer, args or {}
+
+    def __enter__(self):
+        self.ann = self.tracer.annotation(self.name)
+        self.ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        self.ann.__exit__(*exc)
+        self.tracer.complete(self.name, self.t0, self.t1 - self.t0,
+                             **self.args)
         return False
 
 
@@ -404,12 +444,14 @@ class ServeEngine:
     ``tracer`` / ``metrics`` plug the engine into the obs subsystem
     (``repro.obs``): every round records phase spans (``round/admit`` /
     ``round/grant`` / ``round/host_prep`` / ``round/device_step`` /
-    ``round/emit``) and request lifecycle instants per the
+    ``round/emit``), the four ``round/step/*`` parts of each step,
+    ``round/gc`` collector spans and request lifecycle instants per the
     ``obs/trace.py`` naming contract, plus counters/histograms per the
     ``obs/metrics.py`` contract. Both default to the process-wide
     instances (``obs.trace.get_tracer()`` is disabled until e.g.
     ``launch/serve.py --trace-out`` turns it on, so the default engine
-    pays one branch per span site).
+    pays one branch per span site). Phase and step-part seconds and the
+    position counters fill ``EngineStats`` with tracing on or off.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, slots: int = 4,
@@ -653,8 +695,14 @@ class ServeEngine:
         duplicates should key on ``request.uid`` and truncate.
 
         Stats describe this run only (a fresh EngineStats per call); the
-        prefix cache and its pages persist across calls."""
+        prefix cache and its pages persist across calls. With an enabled
+        tracer, Python's collections during the run are ``round/gc``
+        spans."""
         del greedy                     # per-request SamplingParams rule
+        with obs_trace.active(self._tracer).collector_spans():
+            return self._run(requests, on_token)
+
+    def _run(self, requests: List[Request], on_token) -> List[Request]:
         self.stats = EngineStats()
         t0 = time.monotonic()
         for r in requests:
@@ -672,8 +720,15 @@ class ServeEngine:
             "serve_phase_seconds", "per-round phase wall time",
             labels=("phase",))
 
+        span_cls = _TracedPhaseSpan if trc.enabled else _PhaseSpan
+        phase_s = self.stats.phase_seconds
+        part_s = self.stats.step_part_seconds
+
         def phase(name: str) -> _PhaseSpan:
-            return _PhaseSpan(name, trc, phase_hist, self.stats)
+            return span_cls(name, phase_s, phase_hist, trc)
+
+        def part(name: str, args=None, t0=None) -> _PhaseSpan:
+            return span_cls(name, part_s, None, trc, args, t0)
 
         # the pool persists across runs: release slot pages a previously
         # aborted run may have left mapped (cached pages survive), and
@@ -949,47 +1004,67 @@ class ServeEngine:
 
         def gather(rec):
             """Materialize a round's device outputs as full-width host
-            arrays (dead lanes carry DEAD_TOKEN); blocks until the
-            device — and any async readback — is done."""
-            s0 = rec["solo_slot"]
-            if s0 is not None:
-                nxt = np.full((self.slots, rec["c_len"]),
-                              samplib.DEAD_TOKEN, np.int64)
-                logp_h = np.zeros((self.slots, rec["c_len"]),
-                                  np.float32)
-                nxt[s0] = np.asarray(rec["tok_dev"])[0]
-                logp_h[s0] = np.asarray(rec["logp_dev"])[0]
-            else:
-                nxt = np.asarray(rec["tok_dev"])
-                logp_h = np.asarray(rec["logp_dev"])
+            arrays (dead lanes carry DEAD_TOKEN): waits for the device,
+            then finishes the copies dispatch() started."""
+            with part("round/step/wait") as wait:
+                jax.block_until_ready((rec["tok_dev"], rec["logp_dev"]))
+            with part("round/step/readback", t0=wait.t1):
+                s0 = rec["solo_slot"]
+                if s0 is not None:
+                    nxt = np.full((self.slots, rec["c_len"]),
+                                  samplib.DEAD_TOKEN, np.int64)
+                    logp_h = np.zeros((self.slots, rec["c_len"]),
+                                      np.float32)
+                    nxt[s0] = np.asarray(rec["tok_dev"])[0]
+                    logp_h[s0] = np.asarray(rec["logp_dev"])[0]
+                else:
+                    nxt = np.asarray(rec["tok_dev"])
+                    logp_h = np.asarray(rec["logp_dev"])
             return nxt, logp_h
 
-        def readback_async(rec):
-            # start the D2H copy at dispatch time so the retire's
-            # gather finds it complete (or at least in flight)
-            for arr in (rec["tok_dev"], rec["logp_dev"]):
-                arr.copy_to_host_async()
-
-        def dispatch(toks_in, cache_in, start, n_new, solo_slot=None):
+        def dispatch(toks, cache_in, start, n_new, solo_slot=None, t0=None):
             """The round's ONE async step dispatch — never blocks; the
             sync path gathers inline, the pipelined path one round
-            later."""
+            later. ``toks`` is the round's full-width host token array,
+            or the previous round's on-device tokens (pipelined carry);
+            a solo dispatch takes lane ``solo_slot``'s row of it. ``t0``
+            is the enclosing phase's start, where the upload starts."""
+            solo = solo_slot is not None
+            rows = 1 if solo else self.slots
+            c_len = toks.shape[1]
+            self.stats.positions_computed += rows * c_len
+            self.stats.positions_useful += int(n_new.sum())
             # the sampling lanes are copied (jnp.array): set_lane
             # rewrites them in place, see _flush_page_ops
-            if solo_slot is not None:
-                tok_dev, logp_dev, self._arena = self._steps.solo_step(
-                    self._step_params(), toks_in, cache_in,
-                    np.int32(solo_slot),
-                    jnp.asarray(start[solo_slot:solo_slot + 1]),
-                    jnp.asarray(n_new[solo_slot:solo_slot + 1]),
-                    {k: jnp.array(v[solo_slot:solo_slot + 1])
-                     for k, v in self._samp.items()})
+            with part("round/step/upload", t0=t0) as upload:
+                params = self._step_params()
+                lanes = slice(solo_slot, solo_slot + 1) if solo \
+                    else slice(None)
+                if isinstance(toks, np.ndarray):
+                    toks = jnp.asarray(toks[lanes])
+                else:
+                    toks = serve_steps.carry_decode_tokens(toks, solo_slot)
+                step_in = (jnp.asarray(start[lanes]),
+                           jnp.asarray(n_new[lanes]),
+                           {k: jnp.array(v[lanes])
+                            for k, v in self._samp.items()})
+            launch_args = dict(c_len=c_len, rows=rows, solo=solo) \
+                if trc.enabled else None
+            with part("round/step/launch", launch_args, t0=upload.t1):
+                if solo:
+                    tok_dev, logp_dev, self._arena = self._steps.solo_step(
+                        params, toks, cache_in, np.int32(solo_slot),
+                        *step_in)
+                else:
+                    tok_dev, logp_dev, self._arena = self._steps.step(
+                        params, toks, cache_in, *step_in)
+                # both device-to-host copies start now and run right
+                # after the step on the device: gather's explicit wait
+                # then costs no host round trip per array
+                tok_dev.copy_to_host_async()
+                logp_dev.copy_to_host_async()
+            if solo:
                 self.stats.solo_rounds += 1
-            else:
-                tok_dev, logp_dev, self._arena = self._steps.step(
-                    self._step_params(), toks_in, cache_in,
-                    jnp.asarray(start), jnp.asarray(n_new),
-                    {k: jnp.array(v) for k, v in self._samp.items()})
             return tok_dev, logp_dev
 
         def retire(rec, defer_rec=None):
@@ -1008,10 +1083,21 @@ class ServeEngine:
                     pool.free_slot(s)
             return emitted
 
+        def round_args(rec, lanes, emitted) -> dict:
+            """The ``round`` span's args for a round that launched a
+            step (obs/trace.py contract)."""
+            rows = 1 if rec["solo_slot"] is not None else self.slots
+            return dict(lanes=lanes, prefill_lanes=len(rec["plan"]),
+                        decode_lanes=len(rec["act_dec"]), emitted=emitted,
+                        c_len=rec["c_len"], positions=rows * rec["c_len"],
+                        useful=int(rec["n_new"].sum()))
+
         inflight = None            # the dispatched-but-unretired round
 
-        while any(a is not None for a in active) or sched.pending:
-            r_t0 = time.perf_counter()
+        def one_round(rnd) -> bool:
+            """One pass of the round loop inside its ``round`` span
+            ``rnd``; False when no request can be seated any more."""
+            nonlocal inflight
             if inflight is not None:
                 # ---- pipelined fast path: grant pure-decode lanes
                 # against the in-flight round's predicted state, carry
@@ -1052,7 +1138,7 @@ class ServeEngine:
                     self.stats.pipeline_barriers += 1
                     prev, inflight = inflight, None
                     retire(prev)
-                    continue
+                    return True
                 with phase("round/host_prep"):
                     start = np.zeros(self.slots, np.int32)
                     n_new = np.zeros(self.slots, np.int32)
@@ -1068,18 +1154,16 @@ class ServeEngine:
                     cache_in = self._flush_page_ops(pool)
                     solo = (self._steps.solo_step is not None
                             and len(dec) == 1)
-                with phase("round/dispatch"):
+                with phase("round/dispatch") as ph:
                     s0 = dec[0] if solo else None
-                    tok_in = serve_steps.carry_decode_tokens(
-                        inflight["tok_dev"], s0)
                     tok_dev, logp_dev = dispatch(
-                        tok_in, cache_in, start, n_new, solo_slot=s0)
+                        inflight["tok_dev"], cache_in, start, n_new,
+                        solo_slot=s0, t0=ph.t0)
                     rec = {"order": dec, "plan": {}, "verify": {},
                            "act_dec": dec, "n_new": n_new, "c_len": 1,
                            "ts": ts, "tok_dev": tok_dev,
                            "logp_dev": logp_dev, "solo_slot": s0,
                            "lag_free": set()}
-                    readback_async(rec)
                 self.stats.decode_steps += 1
                 self.stats.rounds += 1
                 self.stats.pipelined_rounds += 1
@@ -1089,11 +1173,9 @@ class ServeEngine:
                             free=pool.free_count)
                 trc.counter("sched/queue",
                             prefill_pending=sched.pending)
-                trc.complete("round", r_t0,
-                             time.perf_counter() - r_t0,
-                             lanes=len(order), prefill_lanes=0,
-                             decode_lanes=len(dec), emitted=emitted)
-                continue
+                if trc.enabled:
+                    rnd.args.update(round_args(rec, len(order), emitted))
+                return True
             with phase("round/admit"):
                 sched.start_round()
                 admit()
@@ -1102,7 +1184,7 @@ class ServeEngine:
                     raise PoolExhausted(
                         f"queue head needs more than the whole pool "
                         f"({self.n_pages} pages)")
-                break
+                return False
             # --- plan the round: chunk grants for prefilling lanes, one
             # token per decode lane; every planned lane must own the
             # pages it writes — on exhaustion first evict unpinned cached
@@ -1180,7 +1262,7 @@ class ServeEngine:
                             continue
                         verify[s] = draft
             if not plan and not run_decode:
-                continue            # everything preempted/idled; re-admit
+                return True         # everything preempted/idled; re-admit
 
             with phase("round/host_prep"):
                 max_n = max([max(plan.values(), default=0)]
@@ -1246,28 +1328,20 @@ class ServeEngine:
                        and not verify and self._spec_k == 0
                        and not sched.pending)
             with phase("round/dispatch" if overlap
-                       else "round/device_step"):
+                       else "round/device_step") as ph:
                 # token selection runs INSIDE the jit (the sampling-head
                 # epilogue): only [B, C] selected ids + logprobs cross
                 # the boundary, and dead lanes come back as the
                 # DEAD_TOKEN sentinel — never a forgeable vocab id
-                if solo:
-                    s0 = int(live[0])
-                    tok_dev, logp_dev = dispatch(
-                        jnp.asarray(toks[s0:s0 + 1]), cache_in,
-                        start, n_new, solo_slot=s0)
-                else:
-                    s0 = None
-                    tok_dev, logp_dev = dispatch(
-                        jnp.asarray(toks), cache_in, start, n_new)
+                s0 = int(live[0]) if solo else None
+                tok_dev, logp_dev = dispatch(toks, cache_in, start, n_new,
+                                             solo_slot=s0, t0=ph.t0)
                 rec = {"order": order, "plan": plan, "verify": verify,
                        "act_dec": act_dec, "n_new": n_new,
                        "c_len": c_len, "ts": ts, "tok_dev": tok_dev,
                        "logp_dev": logp_dev, "solo_slot": s0,
                        "lag_free": set()}
-                if overlap:
-                    readback_async(rec)
-                else:
+                if not overlap:
                     nxt, logp_h = gather(rec)
             if act_dec:
                 self.stats.decode_steps += 1
@@ -1285,9 +1359,14 @@ class ServeEngine:
             trc.counter("pool/pages", live=pool.used_count,
                         free=pool.free_count)
             trc.counter("sched/queue", prefill_pending=sched.pending)
-            trc.complete("round", r_t0, time.perf_counter() - r_t0,
-                         lanes=len(order), prefill_lanes=len(plan),
-                         decode_lanes=len(act_dec), emitted=emitted)
+            if trc.enabled:
+                rnd.args.update(round_args(rec, len(order), emitted))
+            return True
+
+        while any(a is not None for a in active) or sched.pending:
+            with trc.span("round") as rnd:
+                if not one_round(rnd):
+                    break
 
         if inflight is not None:
             # every lane finished (or deferred its free) during the lag

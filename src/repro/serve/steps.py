@@ -254,9 +254,9 @@ class TracedJit:
     ``cost_by_key``; every call then lands in ``calls_by_key`` /
     ``seconds_by_key``, measured synchronously (``block_until_ready``
     inside the timed window, so the table holds device time rather than
-    async dispatch time), and emits a cumulative ``cost/<fn>`` Perfetto
-    counter track. ``cost_key(args, kw) -> str`` names the shape (the
-    unified step keys on its token width C); default one key, "call".
+    async dispatch time). ``cost_key(args, kw) -> str`` names the shape
+    (the unified step keys on its token width C); default one key,
+    "call".
     Capture keys on shapes this WRAPPER has seen, not on jit-cache
     growth, so fresh engines over an lru-warm jit still capture.
     Capture off — the default — costs one module-bool branch per call.
@@ -275,8 +275,6 @@ class TracedJit:
         self.cost_by_key: dict = {}      # key -> {"flops", "bytes"}/call
         self.calls_by_key: dict = {}
         self.seconds_by_key: dict = {}
-        self._cum_flops = 0.0
-        self._cum_bytes = 0.0
 
     def _cache_size(self) -> Optional[int]:
         try:
@@ -309,13 +307,6 @@ class TracedJit:
             self.calls_by_key[key] = self.calls_by_key.get(key, 0) + 1
             self.seconds_by_key[key] = \
                 self.seconds_by_key.get(key, 0.0) + dt
-            cost = self.cost_by_key[key]
-            self._cum_flops += cost["flops"]
-            self._cum_bytes += cost["bytes"]
-            if self._cum_flops or self._cum_bytes:
-                obs_trace.get_tracer().counter(
-                    f"cost/{self.name}", flops=self._cum_flops,
-                    bytes=self._cum_bytes)
         after = self._cache_size()
         if before is not None and after is not None and after > before:
             grew = after - before

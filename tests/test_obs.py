@@ -9,11 +9,17 @@ the engine integration invariants the obs contract promises —
     their round's umbrella span;
   * the exported Chrome trace parses and carries the schema Perfetto
     needs (name/ph/ts/pid/tid, dur on "X" events);
+  * the four ``round/step/*`` parts tile the synchronous step and nest
+    in the pipelined phases, without entering host/device seconds;
+  * position counters match a hand count; collector spans and profiler
+    annotations exist only under an enabled tracer;
   * page-op counters (adopt / page_copy / tables_rebuild) land in both
     ``EngineStats`` and ``serve_page_ops_total``;
   * TracedJit attributes compiles to the cold engine only and flags
     cache growth beyond a declared compile surface.
 """
+import collections
+import gc
 import json
 
 import jax
@@ -24,7 +30,7 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import Registry, log_buckets
 from repro.obs.trace import Tracer
-from repro.serve.engine import Request, ServeEngine
+from repro.serve.engine import EngineStats, Request, ServeEngine
 from repro.serve.steps import TracedJit
 
 PAGE = 8
@@ -88,25 +94,6 @@ def test_registry_get_or_create_and_mismatch():
         reg.histogram("h", buckets=(1.0, 3.0))      # bucket drift
 
 
-def test_prometheus_exposition():
-    reg = Registry()
-    reg.counter("req_total", "requests", labels=("kind",)).inc(3, kind="a")
-    reg.gauge("pages").set(7)
-    h = reg.histogram("lat_seconds", "latency", buckets=(0.01, 0.1))
-    h.observe(0.005)
-    h.observe(0.05)
-    h.observe(9.0)
-    text = reg.to_prometheus()
-    assert '# TYPE req_total counter' in text
-    assert 'req_total{kind="a"} 3' in text
-    assert 'pages 7' in text
-    # cumulative le buckets + +Inf + sum/count
-    assert 'lat_seconds_bucket{le="0.01"} 1' in text
-    assert 'lat_seconds_bucket{le="0.1"} 2' in text
-    assert 'lat_seconds_bucket{le="+Inf"} 3' in text
-    assert 'lat_seconds_count 3' in text
-
-
 def test_snapshot_json_roundtrip(tmp_path):
     reg = Registry()
     reg.counter("a_total", labels=("k",)).inc(2, k="x")
@@ -154,7 +141,7 @@ def test_chrome_trace_schema(tmp_path):
     assert doc["displayTimeUnit"] == "ms"
 
 
-def test_span_nesting_and_phase_totals():
+def test_span_nesting():
     t = Tracer()
     with t.span("outer"):
         with t.span("inner"):
@@ -163,8 +150,65 @@ def test_span_nesting_and_phase_totals():
     outer = next(e for e in t.events if e["name"] == "outer")
     assert outer["ts"] <= inner["ts"]
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-6
-    totals = t.phase_totals()
-    assert totals["inner"] <= totals["outer"]
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records the names
+    entered and exited."""
+
+    def __init__(self):
+        self.opened, self.closed = [], []
+
+    def __call__(self, name):
+        rec = self
+
+        class Ann:
+            def __enter__(self):
+                rec.opened.append(name)
+
+            def __exit__(self, *exc):
+                rec.closed.append(name)
+
+        return Ann()
+
+
+def test_enabled_tracer_opens_one_annotation_per_span(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(obs_trace, "_annotation", ann)
+    t = Tracer()
+    with t.span("outer"):
+        with t.span("inner", k=1):
+            pass
+        t.instant("point")
+        t.counter("c", v=1)
+    t.complete("measured", 0.0, 0.0)   # after the fact: nothing to hold
+    assert ann.opened == ["outer", "inner"]
+    assert ann.closed == ["inner", "outer"]
+    with t.annotation("timed"):        # the caller times and records it
+        pass
+    assert ann.opened == ["outer", "inner", "timed"]
+    off = Tracer(enabled=False)
+    with off.span("x"), off.annotation("y"):
+        pass
+    assert ann.opened == ["outer", "inner", "timed"]
+
+
+def test_tracer_collector_spans():
+    before = list(gc.callbacks)
+    t = Tracer()
+    with t.collector_spans():
+        assert len(gc.callbacks) == len(before) + 1
+        gc.collect()
+    assert gc.callbacks == before
+    ev = [e for e in t.events if e["name"] == "round/gc"]
+    assert len(ev) == 1 and ev[0]["ph"] == "X"
+    assert ev[0]["args"]["generation"] == 2
+    assert ev[0]["args"]["collected"] >= 0
+    off = Tracer(enabled=False)
+    with off.collector_spans():
+        assert gc.callbacks == before
+        gc.collect()
+    assert off.events == []
 
 
 def test_default_tracer_swap():
@@ -190,11 +234,17 @@ def _reqs(n=6, seed=3, vocab=64, max_new=6, lo=4, hi=20):
             for i, L in enumerate(rng.integers(lo, hi, size=n))]
 
 
+def _is_phase(name):
+    """A round phase: a ``round/`` span that is neither a step part
+    nested inside a phase nor a collector span."""
+    return (name.startswith("round/") and name != "round/gc"
+            and not name.startswith("round/step/"))
+
+
 def _check_phase_spans(events):
     """Round phase spans must not overlap within a thread, and must sit
     inside their round's umbrella span."""
-    phases = [e for e in events if e["ph"] == "X"
-              and e["name"].startswith("round/")]
+    phases = [e for e in events if e["ph"] == "X" and _is_phase(e["name"])]
     rounds = [e for e in events if e["ph"] == "X" and e["name"] == "round"]
     assert phases and rounds
     by_tid = {}
@@ -245,7 +295,8 @@ def test_engine_trace_and_conservation(serve_cfg, serve_params, tmp_path):
     assert set(s.phase_seconds) >= {"round/admit", "round/host_prep",
                                     "round/device_step", "round/emit"}
     assert s.rounds == sum(1 for e in tracer.events
-                           if e["ph"] == "X" and e["name"] == "round")
+                           if e["ph"] == "X" and e["name"] == "round"
+                           and "c_len" in e.get("args", {}))
     assert s.host_seconds() > 0 and s.device_seconds() > 0
     # exported file is valid Chrome trace JSON
     path = tmp_path / "trace.json"
@@ -306,6 +357,188 @@ def test_engine_page_op_counters(serve_cfg, serve_params):
     adm = reg.counter("serve_admissions_total", labels=("kind",))
     assert adm.value(kind="hit") == s.cache_hits
     assert adm.value(kind="miss") >= 1
+
+
+STEP_PARTS = ("round/step/upload", "round/step/launch", "round/step/wait",
+              "round/step/readback")
+
+
+def _xs(events, name):
+    return [e for e in events if e["ph"] == "X" and e["name"] == name]
+
+
+def _inside(inner, outer, slack=1.0):
+    return (outer["ts"] - slack <= inner["ts"] and inner["ts"]
+            + inner["dur"] <= outer["ts"] + outer["dur"] + slack)
+
+
+def _parts_in(events, outer):
+    parts = [e for e in events if e["ph"] == "X"
+             and e["name"] in STEP_PARTS and _inside(e, outer)]
+    return sorted(parts, key=lambda e: e["ts"])
+
+
+def test_step_parts_tile_the_sync_device_step(serve_cfg, serve_params):
+    tracer = Tracer()
+    eng = ServeEngine(serve_cfg, serve_params, slots=2, max_len=32,
+                      page_size=PAGE, tracer=tracer, metrics=Registry())
+    eng.run(_reqs())
+    steps = _xs(tracer.events, "round/device_step")
+    assert len(steps) == eng.stats.rounds
+    gcs = _xs(tracer.events, "round/gc")
+    for step in steps:
+        parts = _parts_in(tracer.events, step)
+        assert [p["name"] for p in parts] == list(STEP_PARTS)
+        for a, b in zip(parts, parts[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 1.0
+        # the upload starts with the step; launch follows the upload and
+        # readback the wait on the same clock readings
+        upload, launch, wait, readback = parts
+        assert upload["ts"] == step["ts"]
+        for a, b in ((upload, launch), (wait, readback)):
+            assert a["ts"] + a["dur"] == pytest.approx(b["ts"], abs=1e-3)
+        # a collection that starts between two parts is a span of its
+        # own; durations in microseconds: the parts and such collections
+        # leave under 1 ms of the step uncovered
+        between = sum(g["dur"] for g in gcs if _inside(g, step)
+                      and not any(_inside(g, p) for p in parts))
+        uncovered = step["dur"] - sum(p["dur"] for p in parts) - between
+        assert 0 <= uncovered + 1.0 and uncovered < 1e3
+    launches = _xs(tracer.events, "round/step/launch")
+    assert {(e["args"]["rows"], e["args"]["solo"]) for e in launches} \
+        <= {(1, True), (2, False)}
+    s = eng.stats
+    assert set(s.step_part_seconds) == set(STEP_PARTS)
+    assert sum(s.step_part_seconds.values()) <= \
+        s.phase_seconds["round/device_step"]
+
+
+def test_step_parts_nest_in_pipelined_phases(serve_cfg, serve_params):
+    tracer = Tracer()
+    eng = ServeEngine(serve_cfg, serve_params, slots=2, max_len=48,
+                      page_size=PAGE, pipelined=True, tracer=tracer,
+                      metrics=Registry())
+    eng.run(_reqs(n=4, max_new=10))
+    assert eng.stats.pipelined_rounds > 0
+    ev = tracer.events
+    dispatches = _xs(ev, "round/dispatch")
+    retires = _xs(ev, "round/retire")
+    assert dispatches and retires
+    for d in dispatches:
+        assert [p["name"] for p in _parts_in(ev, d)] == list(STEP_PARTS[:2])
+    for r in retires:
+        assert [p["name"] for p in _parts_in(ev, r)] == list(STEP_PARTS[2:])
+    holders = dispatches + retires + _xs(ev, "round/device_step")
+    for name in STEP_PARTS:
+        parts = _xs(ev, name)
+        assert len(parts) == eng.stats.rounds
+        assert all(any(_inside(p, h) for h in holders) for p in parts)
+
+
+def test_step_parts_leave_host_and_device_seconds(serve_cfg, serve_params):
+    tracer = Tracer()
+    eng = ServeEngine(serve_cfg, serve_params, slots=2, max_len=32,
+                      page_size=PAGE, tracer=tracer, metrics=Registry())
+    eng.run(_reqs())
+    s = eng.stats
+    assert not set(s.step_part_seconds) & set(s.phase_seconds)
+    assert all(_is_phase(k) for k in s.phase_seconds)
+    device = EngineStats._DEVICE_PHASES
+    assert s.host_seconds() == sum(v for k, v in s.phase_seconds.items()
+                                   if k not in device)
+    assert s.device_seconds() == sum(s.phase_seconds.get(k, 0.0)
+                                     for k in device)
+    # the trace's phase spans come from the same clock readings as the
+    # stats: each sum agrees to rounding
+    phases = [e for e in tracer.events
+              if e["ph"] == "X" and _is_phase(e["name"])]
+    host = sum(e["dur"] for e in phases if e["name"] not in device)
+    dev = sum(e["dur"] for e in phases if e["name"] in device)
+    assert s.host_seconds() == pytest.approx(host * 1e-6, rel=1e-9)
+    assert s.device_seconds() == pytest.approx(dev * 1e-6, rel=1e-9)
+
+
+def test_positions_counted_by_hand(serve_cfg, serve_params):
+    """Prompts of 5 and 11 tokens (off the 4/8/16/32 rungs) prefill
+    together at C = 16 on both rows; one decode round of both lanes,
+    then two solo rounds of the longer request."""
+    tracer = Tracer()
+    eng = ServeEngine(serve_cfg, serve_params, slots=2, max_len=32,
+                      page_size=PAGE, tracer=tracer, metrics=Registry())
+    rng = np.random.default_rng(7)
+    reqs = [Request(uid=0, prompt=rng.integers(2, 64, 5).astype(np.int32),
+                    max_new_tokens=2),
+            Request(uid=1, prompt=rng.integers(2, 64, 11).astype(np.int32),
+                    max_new_tokens=4)]
+    eng.run(reqs)
+    s = eng.stats
+    assert s.rounds == 4 and s.solo_rounds == 2
+    assert s.positions_computed == 2 * 16 + 2 * 1 + 1 + 1
+    assert s.positions_useful == (5 + 11) + 2 + 1 + 1
+    rounds = [e["args"] for e in _xs(tracer.events, "round")
+              if "c_len" in e.get("args", {})]
+    assert [(a["c_len"], a["positions"], a["useful"]) for a in rounds] \
+        == [(16, 32, 16), (1, 2, 2), (1, 1, 1), (1, 1, 1)]
+
+
+def test_collector_spans_only_with_an_enabled_tracer(serve_cfg,
+                                                     serve_params):
+    before = list(gc.callbacks)
+    for enabled in (True, False):
+        tracer = Tracer(enabled=enabled)
+        eng = ServeEngine(serve_cfg, serve_params, slots=2, max_len=32,
+                          page_size=PAGE, tracer=tracer,
+                          metrics=Registry())
+        hooks = []
+
+        def collect(slot, tok, req):
+            hooks.append(len(gc.callbacks))
+            gc.collect()
+
+        eng.run(_reqs(n=2, max_new=3), on_token=collect)
+        assert gc.callbacks == before
+        assert hooks and set(hooks) == {len(before) + enabled}
+        spans = _xs(tracer.events, "round/gc")
+        if enabled:
+            assert len(spans) >= len(hooks)
+            assert all({"generation", "collected"} <= set(e["args"])
+                       for e in spans)
+        else:
+            assert tracer.events == []
+
+
+def test_collector_hook_removed_when_run_raises(serve_cfg, serve_params):
+    before = list(gc.callbacks)
+
+    def fail(slot, tok, req):
+        raise RuntimeError("stream consumer failed")
+
+    eng = ServeEngine(serve_cfg, serve_params, slots=2, max_len=32,
+                      page_size=PAGE, tracer=Tracer(), metrics=Registry())
+    with pytest.raises(RuntimeError, match="stream consumer"):
+        eng.run(_reqs(n=2), on_token=fail)
+    assert gc.callbacks == before
+
+
+def test_engine_spans_are_profiler_annotations(serve_cfg, serve_params,
+                                               monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(obs_trace, "_annotation", ann)
+    tracer = Tracer()
+    eng = ServeEngine(serve_cfg, serve_params, slots=2, max_len=32,
+                      page_size=PAGE, tracer=tracer, metrics=Registry())
+    eng.run(_reqs())
+    spans = collections.Counter(e["name"] for e in tracer.events
+                                if e["ph"] == "X")
+    assert collections.Counter(ann.opened) == spans
+    assert collections.Counter(ann.closed) == spans
+    assert {"round", "round/device_step", *STEP_PARTS} <= set(spans)
+    ann.opened.clear()
+    quiet = ServeEngine(serve_cfg, serve_params, slots=2, max_len=32,
+                        page_size=PAGE, tracer=Tracer(enabled=False),
+                        metrics=Registry())
+    quiet.run(_reqs())
+    assert ann.opened == []
 
 
 def test_traced_jit_cold_vs_warm(serve_cfg, serve_params):

@@ -327,32 +327,3 @@ def test_engine_run_no_capture_no_report(serve_cfg, serve_params):
     eng.run(_reqs())
     assert eng.last_cost_report is None
     assert "serve_cost_flops_total" not in reg.snapshot()
-
-
-def test_cost_counter_track_via_default_tracer(serve_cfg, serve_params,
-                                               capture):
-    # the cumulative cost/<fn> track goes to the PROCESS tracer (same
-    # routing as the jit/compile instants deep call sites use)
-    trc = obs_trace.Tracer(enabled=True)
-    prev = obs_trace.set_tracer(trc)
-    try:
-        eng = ServeEngine(serve_cfg, serve_params, slots=2, max_len=64,
-                          page_size=PAGE,
-                          metrics=obs_metrics.Registry())
-        eng.run(_reqs())
-    finally:
-        obs_trace.set_tracer(prev)
-    # solo-lane rounds emit on their own cost/solo_step track; every
-    # round lands on exactly one of the two
-    cost_tracks = [e for e in trc.events if e["ph"] == "C"
-                   and e["name"] in ("cost/step", "cost/solo_step")]
-    rows = [r for r in eng.last_cost_report.fns
-            if r.fn in ("step", "solo_step")]
-    if any(r.captured for r in rows):      # backend exposes a cost model
-        assert len(cost_tracks) == eng.stats.rounds
-        for name in ("cost/step", "cost/solo_step"):
-            cum = [e["args"]["bytes"] for e in cost_tracks
-                   if e["name"] == name]
-            assert cum == sorted(cum)      # cumulative, monotonic
-    else:
-        assert cost_tracks == []           # zero-cost rows emit no track
