@@ -132,8 +132,9 @@ def quantize_qtensor(w: jax.Array, cfg: QMCConfig,
         subtile=(r, c))
 
 
-def dequantize_qtensor(qt: QTensor, dtype=jnp.bfloat16) -> jax.Array:
-    """Reassemble the dense weight matrix (the jnp oracle for the kernel)."""
+def _dense_codes(qt: QTensor, out_offset: int = 0) -> jax.Array:
+    """int8 [din, dout]: every element's code gathered back from its
+    stream, ``out_offset`` added on outlier subtiles."""
     r, c = qt.subtile
     gr, gc = qt.is_out.shape
     din, dout = qt.shape
@@ -142,14 +143,58 @@ def dequantize_qtensor(qt: QTensor, dtype=jnp.bfloat16) -> jax.Array:
 
     take_in = jnp.take(qt.in_codes, jnp.where(tags, 0, pos), axis=0)
     take_out = jnp.take(qt.out_codes, jnp.where(tags, pos, 0), axis=0)
-    tiles = jnp.where(tags[:, None, None],
-                      take_out.astype(jnp.float32),
-                      take_in.astype(jnp.float32))          # [n_sub, r, c]
-    dense = (tiles.reshape(gr, gc, r, c).transpose(0, 2, 1, 3)
-             .reshape(din, dout))
-    emask = part.expand_subtile_mask(qt.is_out, (din, dout), qt.subtile)
+    tiles = jnp.where(tags[:, None, None], take_out + jnp.int8(out_offset),
+                      take_in.astype(jnp.int8))             # [n_sub, r, c]
+    return (tiles.reshape(gr, gc, r, c).transpose(0, 2, 1, 3)
+            .reshape(din, dout))
+
+
+def dequantize_qtensor(qt: QTensor, dtype=jnp.bfloat16) -> jax.Array:
+    """Reassemble the dense weight matrix (the jnp oracle for the kernel)."""
+    dense = _dense_codes(qt).astype(jnp.float32)
+    emask = part.expand_subtile_mask(qt.is_out, qt.shape, qt.subtile)
     scale = jnp.where(emask, qt.scale_out, qt.scale_in)
     return (dense * scale).astype(dtype)
+
+
+# A code plane stores outlier codes shifted by PLANE_OUT_OFFSET: with both
+# streams' codes in [-32, 31] (at most 6 bits), an element is an outlier
+# exactly where its stored code is >= PLANE_OUT_OFFSET // 2.
+PLANE_OUT_OFFSET = 64
+PLANE_MAX_BITS = 6
+
+
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["codes", "scale_in", "scale_out"], meta_fields=[])
+@dataclasses.dataclass
+class CodePlane:
+    """A QTensor's codes un-streamed into one int8 per weight, in dense
+    layout: the execution form the serving step reads (1 byte a weight
+    where dense fp32 reads 4). Each element carries its own stream tag
+    (outlier codes are stored + ``PLANE_OUT_OFFSET``), so dequantizing is
+    elementwise and XLA fuses it into the op that reads the weight.
+    Leading dims (a stacked ``[G]`` layer axis) pass through every op."""
+    codes: jax.Array         # [..., din, dout] int8
+    scale_in: jax.Array      # [..., 1, dout] f32
+    scale_out: jax.Array     # [..., 1, dout] f32
+
+    def dequantize(self, dtype=jnp.float32) -> jax.Array:
+        """Bitwise ``dequantize_qtensor`` of the source QTensor."""
+        out = self.codes >= PLANE_OUT_OFFSET // 2
+        code = jnp.where(out, self.codes - jnp.int8(PLANE_OUT_OFFSET),
+                         self.codes)
+        scale = jnp.where(out, self.scale_out, self.scale_in)
+        return (code.astype(jnp.float32) * scale).astype(dtype)
+
+
+def code_plane(qt: QTensor) -> CodePlane:
+    """The CodePlane of ``qt`` (codes of at most PLANE_MAX_BITS bits)."""
+    if max(qt.bits_in, qt.bits_out) > PLANE_MAX_BITS:
+        raise ValueError(f"a code plane holds codes of at most "
+                         f"{PLANE_MAX_BITS} bits, not {qt.bits_in}/"
+                         f"{qt.bits_out}")
+    return CodePlane(codes=_dense_codes(qt, PLANE_OUT_OFFSET),
+                     scale_in=qt.scale_in, scale_out=qt.scale_out)
 
 
 def qmatmul_ref(x: jax.Array, qt: QTensor,

@@ -15,19 +15,20 @@ ShapeDtypeStructs without allocating — the multi-pod dry-run lowers against
 this (the 314B/398B models never exist on the CPU host).
 
 `build_exec_weights(params)` is the serving **weight execution plan**: a
-one-time, per-process lowering of the stream-format leaves into whatever
-the executing backend multiplies fastest. The QMC streams are the
-*storage and transport* format — they are what the memsys DSE charges
-bytes/energy for, and on TPU the stream-direct Pallas kernels
-(``kernels/qmm.py``) consume them as-is. XLA backends without a fused
-dequant-matmul (the CPU serving bench) would otherwise re-materialize
-the dense working set inside every step call; the plan does that
-re-materialization exactly once at engine setup instead (the same
-load-time repack idiom llama.cpp/ExecuTorch use for formats their
-matmul kernels cannot consume directly), so the per-call serving graph
-degenerates to a dense matmul. ``ServeEngine`` builds it lazily and
-keeps the stream-format tree as the source of truth for cost
-attribution (``obs/costs.py`` models bytes/token from the streams).
+one-time, per-process lowering of the stream-format leaves into the form
+the step reads. The QMC streams are the *storage and transport* format —
+they are what the memsys DSE charges bytes/energy for, and the
+stream-direct Pallas kernels (``kernels/qmm.py``) and the mesh path
+consume them as-is. Gathering subtiles out of two streams inside every
+step is what a dense XLA matmul cannot fuse, so the plan un-streams each
+leaf once, at engine setup, into a ``CodePlane``: one int8 code per
+weight in dense layout plus the two per-column scales. The step then
+dequantizes inside the fusion that reads the weight
+(``models/layers.py:matmul_any``) and streams 1 byte a weight, where a
+dense fp32 lowering would stream 4, with the same fp32 weight values.
+``ServeEngine`` keeps the stream-format tree as the source of truth for
+cost attribution (``obs/costs.py`` models bytes/token from the streams);
+``exec_weight_counts`` says what the plan did.
 """
 from __future__ import annotations
 
@@ -40,8 +41,9 @@ import numpy as np
 
 from repro.core.apply import is_quantizable, path_str
 from repro.core.qconfig import QMCConfig
-from repro.core.qtensor import QTensor, quantize_qtensor
-from repro.core.qtensor_sharded import (ShardedQTensor,
+from repro.core.qtensor import (CodePlane, QTensor, code_plane,
+                                quantize_qtensor)
+from repro.core.qtensor_sharded import (ShardedQTensor, dequantize_sharded,
                                         quantize_qtensor_sharded)
 
 ROW_SHARDED = ("wo", "w_down", "out_proj")   # TP shards the input dim
@@ -183,40 +185,74 @@ def serving_params_struct(params_struct, qmc: QMCConfig, tp_shards: int = 1,
                  use_int4=use_int4, min_dim=min_dim)
 
 
-def build_exec_weights(params, dtype=jnp.float32):
+def _is_q(x) -> bool:
+    return isinstance(x, (QTensor, ShardedQTensor))
+
+
+def build_exec_weights(params):
     """Lower a serving-format pytree to its execution form (see module
-    docstring): every QTensor / ShardedQTensor leaf dequantizes to a
-    dense ``dtype`` array of its logical shape (stacked leaves via vmap
-    over the extra leading dims); everything else passes through.
+    docstring): every QTensor leaf, and every single-shard ShardedQTensor
+    leaf, becomes a ``CodePlane`` (stacked ``[G]``/``[G, E]`` leaves via
+    vmap over the extra leading dims; ``code_plane`` raises on codes too
+    wide for a plane); a multi-shard leaf, which a one-chip step reads
+    whole, dequantizes to dense fp32; everything else passes through.
     Returns ``params`` unchanged (same object) when no stream leaves are
     present, so dense engines pay nothing."""
-    from repro.core.qtensor import dequantize_qtensor
-    from repro.core.qtensor_sharded import dequantize_sharded
-
-    def is_q(x):
-        return isinstance(x, (QTensor, ShardedQTensor))
-
-    if not any(is_q(l) for l in
-               jax.tree_util.tree_leaves(params, is_leaf=is_q)):
+    if not any(_is_q(l) for l in
+               jax.tree_util.tree_leaves(params, is_leaf=_is_q)):
         return params
 
     def lower(leaf):
-        if isinstance(leaf, ShardedQTensor):
-            fn = lambda q: dequantize_sharded(q, dtype)  # noqa: E731
-            extra = leaf.in_codes.ndim - 4   # [shards, k, r, c] is rank 4
-        elif isinstance(leaf, QTensor):
-            fn = lambda q: dequantize_qtensor(q, dtype)  # noqa: E731
-            extra = leaf.in_codes.ndim - 3   # [k, r, c] is rank 3
-        else:
+        if not _is_q(leaf):
             return leaf
+        if isinstance(leaf, ShardedQTensor):
+            extra = leaf.in_codes.ndim - 4   # [shards, k, r, c] is rank 4
+            if leaf.n_shards == 1:
+                fn = lambda q: code_plane(q.local(0))  # noqa: E731
+            else:
+                fn = lambda q: dequantize_sharded(  # noqa: E731
+                    q, jnp.float32)
+        else:
+            extra = leaf.in_codes.ndim - 3   # [k, r, c] is rank 3
+            fn = code_plane
         for _ in range(extra):               # stacked [G]/[G, E] leaves
             fn = jax.vmap(fn)
-        # one jit per leaf: XLA fuses gather/select/scale into the output
-        # buffer, where op-by-op dispatch would hold several dense-sized
-        # temporaries at once (GBs for a full-width stack or LM head)
+        # one jit per leaf: XLA fuses the gather and select into the
+        # output buffer, where op-by-op dispatch would hold several
+        # plane-sized temporaries at once
         return jax.jit(fn)(leaf)
 
-    return jax.tree_util.tree_map(lower, params, is_leaf=is_q)
+    return jax.tree_util.tree_map(lower, params, is_leaf=_is_q)
+
+
+def exec_weight_counts(params) -> dict:
+    """What a step consuming ``params`` reads for its matmul weights:
+    ``exec_weight_bytes`` (every array of every matmul weight: code
+    planes with their scales, streams, dense matrices),
+    ``exec_leaves_coded`` (CodePlane leaves) and ``exec_leaves_dense``
+    (dense matmul weights: untileable or multi-shard leaves of a QMC
+    tree, or every weight of a dense one). A dense matmul weight is a
+    float leaf PTQ would consider (``core.apply.is_quantizable``, any
+    width) that is at least 2-D per layer (leaves under ``blocks`` carry
+    the stacked layer dim), so embeddings, norms and biases never
+    count."""
+    def is_w(x):
+        return _is_q(x) or isinstance(x, CodePlane)
+
+    counts = {"exec_weight_bytes": 0, "exec_leaves_coded": 0,
+              "exec_leaves_dense": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            params, is_leaf=is_w)[0]:
+        p = path_str(path)
+        if is_w(leaf):
+            counts["exec_leaves_coded"] += isinstance(leaf, CodePlane)
+            counts["exec_weight_bytes"] += sum(
+                int(a.nbytes) for a in jax.tree_util.tree_leaves(leaf))
+        elif (is_quantizable(p, leaf, min_dim=1)
+              and leaf.ndim - ("blocks" in p.split("/")) >= 2):
+            counts["exec_leaves_dense"] += 1
+            counts["exec_weight_bytes"] += int(leaf.nbytes)
+    return counts
 
 
 def _walk(params, qmc, tp_shards, abstract, use_int4, min_dim):

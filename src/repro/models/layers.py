@@ -1,4 +1,4 @@
-"""Primitive layers: norms, linears (dense or QTensor), rotary embeddings.
+"""Primitive layers: norms, linears (dense or QMC), rotary embeddings.
 
 All functions are pure; parameters are plain pytree leaves. `matmul_any`
 is the single dispatch point where QMC-quantized serving weights enter the
@@ -11,7 +11,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.qtensor import QTensor
+from repro.core.qtensor import CodePlane, QTensor
 from repro.kernels import ops as kops
 
 
@@ -25,7 +25,12 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
 
 def matmul_any(x: jax.Array, w, use_pallas: bool = False,
                tp_dim: int = 1) -> jax.Array:
-    """x @ w where w is dense, a QTensor, or a ShardedQTensor (QMC serving).
+    """x @ w where w is dense, a QTensor, a ShardedQTensor or a CodePlane
+    (QMC serving).
+
+    A CodePlane dequantizes right before the dot, so XLA fuses the
+    convert, select and scale into the fusion that reads the int8 codes
+    and no dense weight is materialized per call.
 
     tp_dim: which weight dim carries tensor parallelism (1 = column-
     parallel wq/w_up..., 0 = row-parallel wo/w_down) — used by the ZeRO-3
@@ -48,6 +53,8 @@ def matmul_any(x: jax.Array, w, use_pallas: bool = False,
         return qmm_sharded_ref(x, w)
     if isinstance(w, QTensor):
         return kops.qmm(x, w, use_pallas=use_pallas)
+    if isinstance(w, CodePlane):
+        return jnp.matmul(x, w.dequantize(x.dtype))
     w = _gather_weight_for_use(x, w, tp_dim)
     return jnp.matmul(x, w.astype(x.dtype))
 

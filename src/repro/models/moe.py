@@ -63,10 +63,13 @@ def moe_block(p: dict, x: jax.Array, cfg, *, tap=None,
 
     # ---- grouped expert FFN (FLOPs = E * C * d * ff terms) --------------
     # Serving with QMC weights: expert streams are QTensor stacks (fields
-    # carry a leading E dim, sharded on `model`); dequantize on the fly.
+    # carry a leading E dim, sharded on `model`), or their code planes
+    # (core/serving_quant.build_exec_weights); dequantize on the fly.
     def _w(name):
         wp = p[name]
-        from repro.core.qtensor import QTensor, dequantize_qtensor
+        from repro.core.qtensor import CodePlane, QTensor, dequantize_qtensor
+        if isinstance(wp, CodePlane):
+            return wp.dequantize(x.dtype)
         if isinstance(wp, QTensor):
             return jax.vmap(lambda q: dequantize_qtensor(q, x.dtype))(wp)
         return wp.astype(x.dtype)

@@ -206,6 +206,12 @@ class EngineStats:
     # live lanes: 1 - useful / computed is the padded share
     positions_computed: int = 0
     positions_useful: int = 0
+    # the weight execution plan (core/serving_quant.exec_weight_counts):
+    # bytes of the matmul weights the step reads, and how many QMC leaves
+    # it reads as int8 code planes / matmul weights it reads dense
+    exec_weight_bytes: int = 0
+    exec_leaves_coded: int = 0
+    exec_leaves_dense: int = 0
     # per-request emission timestamps (time.monotonic), keyed by uid —
     # the source of truth for inter-token latency; a preempted request's
     # discarded emissions are dropped with its tokens
@@ -398,11 +404,12 @@ class ServeEngine:
     way.
 
     ``weight_plan=True`` (default) lowers QMC stream-format weights once
-    at engine setup into the backend's execution form
-    (``core.serving_quant.build_exec_weights``) so the per-call step
-    graph is as lean as the dense engine's; ``self.params`` keeps the
-    stream tree for cost attribution. Dense weights are unaffected;
-    mesh engines keep TP-local stream compute regardless.
+    at engine setup into int8 code planes
+    (``core.serving_quant.build_exec_weights``), which the step
+    dequantizes inside the fusions that read them; ``self.params`` keeps
+    the stream tree for cost attribution, and ``EngineStats.exec_*``
+    records the plan. Dense weights are unaffected; mesh engines keep
+    TP-local stream compute regardless.
 
     Per round, all page maintenance (COW copies, admission state resets,
     the device block-table rebuild) is queued host-side and flushed in
@@ -505,7 +512,6 @@ class ServeEngine:
         self.chunk = chunk_tokens or serve_steps.default_chunk(
             self.max_pages_per_seq, page_size)
         self._widths = serve_steps.width_ladder(self.chunk)
-        self.stats = EngineStats()
         self.paged_attention = paged_attention
         self._tracer = tracer          # None -> process default at run()
         self._metrics = metrics
@@ -544,14 +550,18 @@ class ServeEngine:
         # cost attribution); the step consumes the one-time execution
         # lowering. Single-device only — mesh engines run TP-local
         # through qmm_shard_map on the streams themselves.
+        from repro.core.serving_quant import (build_exec_weights,
+                                              exec_weight_counts)
         self._weight_plan = weight_plan and mesh is None
         self._exec_params = None
         if self._weight_plan:
             # build at engine setup, like the jit warm-up: run() walls
             # must measure serving, not the one-time lowering
-            from repro.core.serving_quant import build_exec_weights
             self._exec_params = jax.block_until_ready(
                 build_exec_weights(self.params))
+        # what the step's weights cost, recorded on every run's stats
+        self._exec_counts = exec_weight_counts(self._step_params())
+        self.stats = EngineStats(**self._exec_counts)
         # page ops queued by seat() and flushed once per round through
         # the fused apply_page_ops jit (steps without it — a prebuilt
         # legacy step_set — keep the one-dispatch-per-op path)
@@ -601,14 +611,9 @@ class ServeEngine:
         return self._pool
 
     def _step_params(self):
-        """Params tree the jitted step consumes: the lazily built weight
-        execution plan, or the raw tree when the plan is off / mesh."""
-        if not self._weight_plan:
-            return self.params
-        if self._exec_params is None:
-            from repro.core.serving_quant import build_exec_weights
-            self._exec_params = build_exec_weights(self.params)
-        return self._exec_params
+        """Params tree the jitted step consumes: the weight execution
+        plan built at setup, or the raw tree when the plan is off / mesh."""
+        return self._exec_params if self._weight_plan else self.params
 
     def _flush_page_ops(self, pool: PagedKVPool):
         """Apply the round's queued page copies / state resets and the
@@ -703,7 +708,7 @@ class ServeEngine:
             return self._run(requests, on_token)
 
     def _run(self, requests: List[Request], on_token) -> List[Request]:
-        self.stats = EngineStats()
+        self.stats = EngineStats(**self._exec_counts)
         t0 = time.monotonic()
         for r in requests:
             if len(r.prompt) > self.max_len:

@@ -191,6 +191,139 @@ def test_weight_plan_parity(serve_cfg, serve_params):
     assert dense._exec_params is serve_params
 
 
+QMC = QMCConfig(rho=0.3, granularity="subtile")
+
+
+def _is_stream(x):
+    from repro.core.qtensor import QTensor
+    from repro.core.qtensor_sharded import ShardedQTensor
+    return isinstance(x, (QTensor, ShardedQTensor))
+
+
+def _dense_fp32_plan(params):
+    """The former weight plan, kept as the oracle: every stream leaf
+    dequantized to a dense fp32 array (vmap over stacked leading dims)."""
+    import jax.numpy as jnp
+
+    from repro.core.qtensor import dequantize_qtensor
+    from repro.core.qtensor_sharded import ShardedQTensor, dequantize_sharded
+
+    def lower(leaf):
+        if not _is_stream(leaf):
+            return leaf
+        if isinstance(leaf, ShardedQTensor):
+            fn = lambda q: dequantize_sharded(q, jnp.float32)  # noqa: E731
+            extra = leaf.in_codes.ndim - 4
+        else:
+            fn = lambda q: dequantize_qtensor(q, jnp.float32)  # noqa: E731
+            extra = leaf.in_codes.ndim - 3
+        for _ in range(extra):
+            fn = jax.vmap(fn)
+        return jax.jit(fn)(leaf)
+    return jax.tree_util.tree_map(lower, params, is_leaf=_is_stream)
+
+
+@pytest.mark.parametrize("kind", ["qtensor", "stacked_sharded",
+                                  "untileable"])
+def test_code_plane_is_bitwise_dequant(kind):
+    """A code plane's dequantized values are the stream dequantizer's,
+    bit for bit, for a QTensor and a [G]-stacked single-shard
+    ShardedQTensor; an untileable weight stays the same dense array."""
+    import jax.numpy as jnp
+
+    from repro.core.qtensor import CodePlane, quantize_qtensor
+    from repro.core.serving_quant import build_exec_weights
+
+    key = jax.random.PRNGKey(7)
+    if kind == "qtensor":
+        leaf = quantize_qtensor(jax.random.normal(key, (64, 256)), QMC)
+        tree = {"w": leaf}
+    else:
+        dense = {"w": jax.random.normal(key, (3, 64, 256)),
+                 "w_odd": jax.random.normal(key, (3, 64, 96))}
+        tree = quantize_for_serving(dense, QMC, tp_shards=1, min_dim=64)
+        assert not _is_stream(tree["w_odd"])
+    planned = build_exec_weights(tree)
+    if kind == "untileable":
+        assert planned["w_odd"] is tree["w_odd"]
+        return
+    plane = planned["w"]
+    assert isinstance(plane, CodePlane) and plane.codes.dtype == jnp.int8
+    want = np.asarray(_dense_fp32_plan(tree)["w"])
+    got = np.asarray(plane.dequantize(jnp.float32))
+    assert plane.codes.shape == want.shape
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  want.view(np.uint32))
+
+
+def test_code_plane_refuses_wide_codes():
+    """Codes wider than a plane holds raise at plan time; the plan has
+    no dense fallback for them."""
+    from repro.core.qtensor import quantize_qtensor
+    from repro.core.serving_quant import build_exec_weights
+
+    wide = QMCConfig(rho=0.3, granularity="subtile", bits_out=8)
+    leaf = quantize_qtensor(jax.random.normal(jax.random.PRNGKey(7),
+                                              (64, 256)), wide)
+    with pytest.raises(ValueError, match="at most 6 bits"):
+        build_exec_weights({"w": leaf})
+
+
+@pytest.mark.parametrize("cfg_name", ["serve_cfg", "tiny_moe"])
+def test_code_plane_engine_parity(cfg_name, request):
+    """The paged engine on code planes emits the same greedy tokens and
+    logprobs as on the dense fp32 plan (the oracle), MoE expert stacks
+    included."""
+    from repro.models.model import init_params
+    from repro.serve.sampling import SamplingParams
+
+    cfg = request.getfixturevalue(cfg_name)
+    qparams = quantize_for_serving(init_params(cfg, jax.random.PRNGKey(0)),
+                                   QMC, tp_shards=1, min_dim=64)
+
+    def reqs():
+        out = _reqs(n=4, vocab=cfg.vocab)
+        for r in out:
+            r.sampling = SamplingParams(logprobs=True)
+        return out
+
+    planes = _engine(cfg, qparams)
+    out_c = planes.run(reqs())
+    oracle = _engine(cfg, qparams)
+    oracle._exec_params = _dense_fp32_plan(qparams)
+    out_d = oracle.run(reqs())
+    assert planes.stats.exec_leaves_coded > 0
+    assert [r.out_tokens for r in out_c] == [r.out_tokens for r in out_d]
+    for rc, rd in zip(out_c, out_d):
+        np.testing.assert_allclose(rc.out_logprobs, rd.out_logprobs,
+                                   rtol=0, atol=1e-6)
+
+
+def test_exec_weight_counters_hand_count(serve_cfg, serve_params):
+    """The plan's counters on the tiny config: w_gate and w_up [2, 64,
+    128] tile into (8, 128) subtiles and take the code plane (int8 codes
+    plus two f32 [2, 1, 128] scales); wq, wk, wv, wo, w_down and the LM
+    head do not tile (a width of 64) and stay dense fp32. A dense
+    engine reads all eight dense; embeddings and norms never count."""
+    qparams = quantize_for_serving(serve_params, QMC, tp_shards=1,
+                                   min_dim=64)
+    g, d, ff, kv = serve_cfg.n_layers, serve_cfg.d_model, serve_cfg.d_ff, \
+        serve_cfg.kv_dim
+    coded = 2 * (g * d * ff * 1 + 2 * g * ff * 4)
+    dense = 4 * (g * (d * d + 2 * d * kv + d * d) + g * ff * d
+                 + d * serve_cfg.vocab)
+    eng = _engine(serve_cfg, qparams)
+    eng.run(_reqs(n=2))
+    s = eng.stats
+    assert (s.exec_leaves_coded, s.exec_leaves_dense) == (2, 6)
+    assert s.exec_weight_bytes == coded + dense
+    plain = _engine(serve_cfg, serve_params)
+    assert (plain.stats.exec_leaves_coded,
+            plain.stats.exec_leaves_dense) == (0, 8)
+    assert plain.stats.exec_weight_bytes == 4 * (
+        g * (2 * d * d + 2 * d * kv + 3 * d * ff) + d * serve_cfg.vocab)
+
+
 def test_pure_decode_rounds_skip_flush(serve_cfg, serve_params):
     """Rounds that neither allocated, COWed, nor reset anything must not
     dispatch apply_page_ops at all: flush count stays well below round

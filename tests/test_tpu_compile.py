@@ -111,3 +111,24 @@ def test_qmm_compiles(one_chip, kernel, k, n):
 
     compiled = jax.jit(mm).lower(x, qt).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_code_plane_matmul_fuses_dequant(one_chip):
+    """A code-plane weight at decode width: the dot's fusion reads the
+    int8 codes itself and dequantizes in place, so no dense [K, N] f32
+    weight is materialized per call."""
+    from repro.core.qtensor import CodePlane
+    from repro.models.layers import matmul_any
+
+    plane = CodePlane(codes=_sds(one_chip, (D_MODEL, D_FF), jnp.int8),
+                      scale_in=_sds(one_chip, (1, D_FF), jnp.float32),
+                      scale_out=_sds(one_chip, (1, D_FF), jnp.float32))
+    x = _sds(one_chip, (8, D_MODEL), jnp.float32)
+    compiled = jax.jit(matmul_any).lower(x, plane).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        D_MODEL * D_FF * 4
+    # computations are separated by blank lines; the header names params
+    dots = [c for c in compiled.as_text().split("\n\n")
+            if " convolution(" in c]
+    assert dots
+    assert all(f"s8[{D_MODEL},{D_FF}]" in c.splitlines()[0] for c in dots)
