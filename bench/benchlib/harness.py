@@ -5,6 +5,9 @@ per-layer metric lives in files found by name:
 
   bench/configs/<config>.json   sizes, QMC format, weight seed (the file
                                 ``BENCHMARK.json`` names for the config)
+  bench/references/<name>.py    the block's plain reference and its
+                                operation and byte counts, named by the
+                                config's ``"reference"`` key
   bench/traffic/<traffic>.json  parameters of the one traffic generator
   bench/cells/<workload>.json   the limit of the correctness check
   bench/metrics/<metric>.py     ``read(run)`` -> number, dict or None
@@ -15,10 +18,12 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 import time
@@ -36,6 +41,10 @@ CACHE_DIR = os.path.join(BENCH_DIR, ".cache")
 # the serving step's compiled programs (the unified ragged step and its
 # single-lane form), by the names XLA gives their modules
 STEP_PROGRAMS = ("jit_step", "jit_solo")
+# what an architecture module under bench/references/ defines (see
+# benchlib/reference.py)
+REFERENCE_API = ("hidden_states", "head", "logits", "matmuls", "other_bytes",
+                 "state_bytes", "attn_flops", "seq_attn_flops")
 
 
 class NoAccelerator(RuntimeError):
@@ -57,6 +66,7 @@ class Cell:
     chips: int
     config: dict
     config_path: str
+    arch: object          # the config's module under bench/references/
     mix: dict
     limits: dict
     end_to_end: List[dict]
@@ -74,6 +84,7 @@ def load_cell(workload: str, root: str = ROOT,
     cfg_path = os.path.join(root, cfg_entry["file"])
     with open(cfg_path) as f:
         config = json.load(f)
+    arch = reference_module(config, cfg_path, root)
     mix = traffic.load_mix(os.path.join(root, "bench", "traffic",
                                         f"{wl['traffic']}.json"))
     with open(os.path.join(root, "bench", "cells",
@@ -84,9 +95,40 @@ def load_cell(workload: str, root: str = ROOT,
         return "workloads" not in m or workload in m["workloads"]
 
     return Cell(name=workload, chips=wl["chips"], config=config,
-                config_path=cfg_path, mix=mix, limits=limits,
+                config_path=cfg_path, arch=arch, mix=mix, limits=limits,
                 end_to_end=[m for m in bench["end_to_end"] if mine(m)],
                 per_layer=[m for m in bench["per_layer"] if mine(m)])
+
+
+def reference_module(config: dict, config_path: str, root: str = ROOT):
+    """The module ``bench/references/<name>.py`` that the configuration's
+    ``"reference"`` key names. There is no default: a configuration
+    without the key, or naming a module that is not there or lacks part
+    of the interface, is refused."""
+    name = config.get("reference")
+    if not isinstance(name, str) or not re.fullmatch(r"\w[\w.-]*", name):
+        raise ValueError(f"{config_path}: needs a \"reference\" key naming "
+                         f"its module under bench/references/, got {name!r}")
+    path = os.path.join(root, "bench", "references", f"{name}.py")
+    if not os.path.isfile(path):
+        raise ValueError(f"{config_path}: reference {name!r} has no module "
+                         f"{path}")
+    mod = _load_module(os.path.realpath(path), f"bench_reference_{name}")
+    missing = [f for f in REFERENCE_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ValueError(f"{config_path}: reference module {path} lacks "
+                         f"{', '.join(missing)}")
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def _load_module(path: str, name: str):
+    """Each module file once a process, so that its jitted functions keep
+    their compiled programs from one cell to the next."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_reader(name: str, root: str = ROOT):
@@ -317,7 +359,7 @@ def check_sample(records: List[CallRecord], n: int, seed: int):
 
 
 def check(cell: Cell, sample) -> Dict[str, float]:
-    gaps = reference.served_gaps(cell.config["model"],
+    gaps = reference.served_gaps(cell.arch, cell.config["model"],
                                  cell.config["weights"],
                                  cell.config["weight_seed"], sample)
     return {"max_logit_gap": float(np.max(gaps["served"])),
@@ -337,6 +379,7 @@ class RunView:
     slots: int
     page: int
     model: dict
+    arch: object          # the config's module under bench/references/
     weights: dict
     kv_dtype: str
     peaks: dict
@@ -531,7 +574,7 @@ def run(opts, t_process: float, *, root: str = ROOT,
 
     if opts.trace:
         view = RunView(records, info["window_s"], cell.mix["slots"],
-                       st.eng.page, cell.config["model"],
+                       st.eng.page, cell.config["model"], cell.arch,
                        cell.config["weights"], cell.config["kv_dtype"],
                        None if allow_cpu else peaks.peaks_for(
                            device["kind"]), summary)

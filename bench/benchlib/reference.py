@@ -6,23 +6,38 @@ definition draws (``jax.random`` with the model's key layout), quantizes
 every matrix with its own copy of the QMC post-training quantizer
 (subtile outlier selection, noise-aware inlier scale search, plain MSE
 outlier scale search, round-to-nearest codes), dequantizes, and runs the
-dense decoder block as the repository defines it at this commit:
+block of the configuration's architecture at ``precision="highest"``,
+layer by layer, so that it fits on one chip once the program's state is
+freed. ``matmul="fp8"`` is the control: every matmul operand rounded to
+float8 e4m3 with a per-tensor scale, the step below the bfloat16 passes
+the configuration states.
 
-  x = embed[tokens]
-  per layer:  h = rmsnorm(x) * (1 + norm1)
-              q, k, v = h @ wq + bq, h @ wk + bk, h @ wv + bv
-              partial rotary (rotate-half) on the first rotary_pct of
-              each head; causal softmax(q k^T / sqrt(hd)) v
-              x = x + attn @ wo
-              h = rmsnorm(x) * (1 + norm2)
-              x = x + (silu(h @ w_gate) * (h @ w_up)) @ w_down   (gated)
-                  x + gelu_tanh(h @ w_up) @ w_down                (plain)
-  logits = rmsnorm(x) * (1 + final_norm) @ lm_head
+What every architecture shares lives here: the quantizer, the matmul and
+its control, rotary, causal attention, RMSNorm, the tanh GELU, the model
+definition's top-level keys and the embedding rows, and ``served_gaps``.
+The block lives in ``bench/references/<name>.py``, the module that the
+configuration file's ``"reference"`` key names (``harness.load_cell``
+loads it). Such a module defines
 
-at ``precision="highest"``, layer by layer, so that it fits on one chip
-once the program's state is freed. ``matmul="fp8"`` is the control: every
-matmul operand rounded to float8 e4m3 with a per-tensor scale, the step
-below the bfloat16 passes the configuration states.
+  hidden_states(model, weights, seed, tokens, matmul)  final hidden states
+                                                       [N, L, d]
+  head(model, weights, seed)          the LM head's served weights
+  logits(model, x, head, matmul)      final norm and head: [M, vocab]
+
+and, for ``counts.py``, the operations and bytes that depend on the block:
+
+  matmuls(model)                      ([(name, din, dout, copies)], head
+                                      (din, dout)): every weight matrix a
+                                      token is multiplied by, with how
+                                      many of it the model holds
+  other_bytes(model, batch, dtype)    norms, biases and embedding rows a
+                                      decode step of ``batch`` lanes reads
+  state_bytes(model, batch, ctx_tokens, dtype)
+                                      cached state that step reads
+  attn_flops(model, ctx_tokens)       attention of one query per lane
+                                      over ``ctx_tokens`` cached positions
+  seq_attn_flops(model, n)            attention of ``n`` positions
+                                      processed in order
 """
 from __future__ import annotations
 
@@ -35,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 # ---------------------------------------------------------------------------
-# random initial weights, in the model definition's key layout
+# the model definition's top-level keys
 # ---------------------------------------------------------------------------
 
 
@@ -45,31 +60,9 @@ def _normal(key, shape, std):
 
 def top_keys(seed: int):
     """The model definition's eight top-level keys: [0] embedding,
-    [1] LM head, [2] split into one key per layer."""
+    [1] LM head, [2] split into one key per group of layers (one layer a
+    group where the block pattern has one slot)."""
     return jax.random.split(jax.random.PRNGKey(seed), 8)
-
-
-def init_layer(model: dict, key) -> Dict[str, jax.Array]:
-    """One decoder layer's float32 initial weights from its group key."""
-    d, ff, nl = model["d_model"], model["d_ff"], model["n_layers"]
-    q_dim = model["n_heads"] * model["head_dim"]
-    kv_dim = model["n_kv_heads"] * model["head_dim"]
-    (block_key,) = jax.random.split(key, 1)   # pattern ("attn",): one slot
-    ks = jax.random.split(block_key, 3)
-    ka = jax.random.split(ks[0], 4)
-    km = jax.random.split(ks[2], 3)
-    w = {
-        "wq": _normal(ka[0], (d, q_dim), 1.0 / math.sqrt(d)),
-        "wk": _normal(ka[1], (d, kv_dim), 1.0 / math.sqrt(d)),
-        "wv": _normal(ka[2], (d, kv_dim), 1.0 / math.sqrt(d)),
-        "wo": _normal(ka[3], (q_dim, d),
-                      1.0 / math.sqrt(q_dim * 2 * nl)),
-        "w_up": _normal(km[1], (d, ff), 1.0 / math.sqrt(d)),
-        "w_down": _normal(km[2], (ff, d), 1.0 / math.sqrt(ff * 2 * nl)),
-    }
-    if model["gated_mlp"]:
-        w["w_gate"] = _normal(km[0], (d, ff), 1.0 / math.sqrt(d))
-    return w
 
 
 # ---------------------------------------------------------------------------
@@ -216,41 +209,6 @@ def _attention(q, k, v, matmul: str):
     return jnp.swapaxes(o, 0, 1).reshape(L, h * hd)
 
 
-@functools.partial(jax.jit, static_argnames=("model_items", "weight_items",
-                                             "matmul"))
-def _layer(x, key, *, model_items, weight_items, matmul):
-    """One decoder layer over x [N, L, d], its weights made from ``key``."""
-    model, weights = dict(model_items), dict(weight_items)
-    weights["subtile"] = tuple(weights["subtile"])
-    w = {k: served_matrix(v, weights)
-         for k, v in init_layer(model, key).items()}
-    n, L, d = x.shape
-    nh, nkv, hd = model["n_heads"], model["n_kv_heads"], model["head_dim"]
-    eps = model["norm_eps"]
-    pos = jnp.arange(L)
-    # norms and qkv biases start at zero in the model definition
-    h = _rmsnorm(x, 0.0, eps).reshape(n * L, d)
-    q = _mm(h, w["wq"], matmul).reshape(n, L, nh, hd)
-    k = _mm(h, w["wk"], matmul).reshape(n, L, nkv, hd)
-    v = _mm(h, w["wv"], matmul).reshape(n, L, nkv, hd)
-
-    def one(args):
-        qi, ki, vi = args
-        qi = _rotary(qi, pos, model["rotary_pct"], model["rope_theta"])
-        ki = _rotary(ki, pos, model["rotary_pct"], model["rope_theta"])
-        return _attention(qi, ki, vi, matmul)
-
-    att = jax.lax.map(one, (q, k, v)).reshape(n * L, nh * hd)
-    x = x + _mm(att, w["wo"], matmul).reshape(n, L, d)
-    h = _rmsnorm(x, 0.0, eps).reshape(n * L, d)
-    if model["gated_mlp"]:
-        a = jax.nn.silu(_mm(h, w["w_gate"], matmul)) * _mm(h, w["w_up"],
-                                                           matmul)
-    else:
-        a = _gelu_tanh(_mm(h, w["w_up"], matmul))
-    return x + _mm(a, w["w_down"], matmul).reshape(n, L, d)
-
-
 @functools.partial(jax.jit, static_argnames=("model_items",))
 def _embed_rows(tokens, key, *, model_items):
     model = dict(model_items)
@@ -258,44 +216,17 @@ def _embed_rows(tokens, key, *, model_items):
     return jnp.take(table, tokens, axis=0)
 
 
-@functools.partial(jax.jit, static_argnames=("model_items", "weight_items"))
-def _head(key, *, model_items, weight_items):
-    model, weights = dict(model_items), dict(weight_items)
-    weights["subtile"] = tuple(weights["subtile"])
-    d = model["d_model"]
-    w = _normal(key, (d, model["vocab"]), d ** -0.5)
-    return served_matrix(w, weights)
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "matmul"))
-def _logits(x, head, *, eps, matmul):
-    return _mm(_rmsnorm(x, 0.0, eps), head, matmul)
-
-
 def _items(d: dict):
     return tuple(sorted((k, tuple(v) if isinstance(v, list) else v)
                         for k, v in d.items()))
 
 
-def hidden_states(model: dict, weights: dict, seed: int, tokens: np.ndarray,
-                  matmul: str = "highest") -> jax.Array:
-    """Final-layer hidden states [N, L, d] of right-padded ``tokens``
-    [N, L] (causal, so padding never reaches a real position)."""
-    keys = top_keys(seed)
-    mi, wi = _items(model), _items(weights)
-    x = _embed_rows(jnp.asarray(tokens), keys[0], model_items=mi)
-    lkeys = jax.random.split(keys[2], model["n_layers"])
-    for layer in range(model["n_layers"]):
-        x = _layer(x, lkeys[layer], model_items=mi, weight_items=wi,
-                   matmul=matmul)
-    return x
-
-
-def served_gaps(model: dict, weights: dict, seed: int,
+def served_gaps(arch, model: dict, weights: dict, seed: int,
                 seqs: Sequence[Tuple[np.ndarray, List[int]]],
                 controls: Sequence[str] = ()) -> Dict[str, np.ndarray]:
     """Per served token, how far its reference logit lies below the
-    reference's best at that position.
+    reference's best at that position, through ``arch``, the
+    configuration's module under ``bench/references/``.
 
     ``seqs`` holds (prompt, served tokens) pairs. Returns ``{"served":
     gaps}`` and, for each control precision in ``controls``, the gap of
@@ -314,23 +245,19 @@ def served_gaps(model: dict, weights: dict, seed: int,
             served.append(int(t))
     rows, cols = np.asarray(rows), np.asarray(cols)
     served = np.asarray(served)
-    keys = top_keys(seed)
-    mi, wi = _items(model), _items(weights)
-    head = _head(keys[1], model_items=mi, weight_items=wi)
+    head = arch.head(model, weights, seed)
     out = {}
     with jax.default_matmul_precision("highest"):
-        x = hidden_states(model, weights, seed, tokens)
-        ref = _logits(x[rows, cols], head, eps=model["norm_eps"],
-                      matmul="highest")
+        x = arch.hidden_states(model, weights, seed, tokens)
+        ref = arch.logits(model, x[rows, cols], head, "highest")
         del x
         best = jnp.max(ref, axis=-1)
         out["seq"] = rows
         out["served"] = np.asarray(
             best - ref[jnp.arange(len(served)), served])
         for c in controls:
-            xc = hidden_states(model, weights, seed, tokens, matmul=c)
-            lc = _logits(xc[rows, cols], head, eps=model["norm_eps"],
-                         matmul=c)
+            xc = arch.hidden_states(model, weights, seed, tokens, matmul=c)
+            lc = arch.logits(model, xc[rows, cols], head, c)
             top = jnp.argmax(lc, axis=-1)
             out[c] = np.asarray(best - ref[jnp.arange(len(served)), top])
     return out

@@ -45,7 +45,7 @@ def readings(workload, seeds, seconds, t_process, **kw):
     gc.collect()
     flat = [s for smp in samples for s in smp]
     owner = np.repeat(np.arange(len(seeds)), [len(s) for s in samples])
-    gaps = reference.served_gaps(cell.config["model"],
+    gaps = reference.served_gaps(cell.arch, cell.config["model"],
                                  cell.config["weights"],
                                  cell.config["weight_seed"], flat,
                                  controls=(CONTROL,))

@@ -1,8 +1,9 @@
 """Share of its roofline that the mean decode step reaches: the least time
 of that step (the larger of its FLOPs over peak FLOP/s and its bytes over
-peak HBM bandwidth, counted from the configuration's shapes with weights
-in the declared QMC format and KV at the model's dtype) over the measured
-median step time. The result names the bound that applies."""
+peak HBM bandwidth, counted from the configuration's shapes by its
+reference module, with weights in the declared QMC format and KV at the
+model's dtype) over the measured median step time. The result names the
+bound that applies."""
 from benchlib import counts, harness, trace_reduce
 
 
@@ -16,7 +17,7 @@ def read(run):
     lanes = sum(r.stats.tokens_out - r.stats.prefills
                 for r in run.records) / steps
     ctx = sum(r.stats.kv_pages_live for r in run.records) * run.page / steps
-    need = counts.decode_step(run.model, run.weights, lanes, ctx,
+    need = counts.decode_step(run.arch, run.model, run.weights, lanes, ctx,
                               run.kv_dtype)
     least, bound = counts.least_time(need["flops"], need["bytes"],
                                      run.peaks)

@@ -1,6 +1,7 @@
 """A throw-away checkout layout for CPU rehearsals of the harness: a
-``BENCHMARK.json`` with one tiny cell, the real metric readers, and the
-test configuration and traffic mix under ``data/``."""
+``BENCHMARK.json`` with one tiny cell, the real metric readers and
+reference modules, and the test configuration and traffic mix under
+``data/``."""
 import json
 import os
 import shutil
@@ -15,9 +16,10 @@ def make_root(tmp, limit=1.0, per_layer=None, workload="tiny.tiny"):
     root = str(tmp)
     for sub in ("configs", "traffic", "cells"):
         os.makedirs(os.path.join(root, "bench", sub), exist_ok=True)
-    shutil.copytree(os.path.join(BENCH, "metrics"),
-                    os.path.join(root, "bench", "metrics"),
-                    dirs_exist_ok=True)
+    for sub in ("metrics", "references"):
+        shutil.copytree(os.path.join(BENCH, sub),
+                        os.path.join(root, "bench", sub),
+                        dirs_exist_ok=True)
     shutil.copy(os.path.join(HERE, "data", "tiny.json"),
                 os.path.join(root, "bench", "configs", "tiny.json"))
     shutil.copy(os.path.join(HERE, "data", "tiny-mix.json"),

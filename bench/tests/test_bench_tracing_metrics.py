@@ -24,7 +24,7 @@ def _view(*stats):
     """A RunView holding one ``run()`` call per stats object."""
     return harness.RunView(
         records=[harness.CallRecord(0.0, 1.0, [], s) for s in stats],
-        window_s=1.0, slots=1, page=16, model={}, weights={},
+        window_s=1.0, slots=1, page=16, model={}, arch=None, weights={},
         kv_dtype="bfloat16", peaks=None, trace=None)
 
 

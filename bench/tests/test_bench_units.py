@@ -13,8 +13,10 @@ sys.path.insert(0, HERE)
 
 from benchlib import counts, harness, peaks, trace_reduce, traffic  # noqa: E402
 
-STABLELM = json.load(open(os.path.join(os.path.dirname(HERE), "configs",
-                                       "stablelm-1.6b.json")))
+STABLELM_PATH = os.path.join(os.path.dirname(HERE), "configs",
+                             "stablelm-1.6b.json")
+STABLELM = json.load(open(STABLELM_PATH))
+DENSE = harness.reference_module(STABLELM, STABLELM_PATH)
 
 
 # ---------------------------------------------------------------------------
@@ -200,15 +202,15 @@ def test_avg_bits_and_matrix_bytes():
 
 def test_stablelm_params_flops_and_bytes():
     m, w = STABLELM["model"], STABLELM["weights"]
-    p = counts.matmul_params(m)
+    p = counts.matmul_params(DENSE, m)
     per_layer = 4 * 2048 * 2048 + 3 * 2048 * 5632
     assert p["layers"] == 24 * per_layer == 1_233_125_376
     assert p["head"] == 2048 * 100352 == 205_520_896
-    qbytes = counts.qmc_weight_bytes(m, w)
+    qbytes = counts.qmc_weight_bytes(DENSE, m, w)
     # 3.6 bits a weight plus ~0.06 bits of tags, positions and scales
     assert qbytes == pytest.approx((p["layers"] + p["head"]) * 3.66 / 8,
                                    rel=0.002)
-    step = counts.decode_step(m, w, batch=1, ctx_tokens=512,
+    step = counts.decode_step(DENSE, m, w, batch=1, ctx_tokens=512,
                               kv_dtype="bfloat16")
     kv = 512 * 24 * 2 * 2048 * 2
     assert step["bytes"] == pytest.approx(
@@ -223,11 +225,11 @@ def test_stablelm_params_flops_and_bytes():
 
 def test_request_flops_counts_no_padding():
     m = STABLELM["model"]
-    p = counts.matmul_params(m)
+    p = counts.matmul_params(DENSE, m)
     # prompt 3, 2 outputs: positions 0..3 processed, head at 2 positions
     want = 2 * 4 * p["layers"] + 2 * 2 * p["head"] \
         + 4 * (1 + 2 + 3 + 4) * 2048 * 24
-    assert counts.request_flops(m, 3, 2) == want
+    assert counts.request_flops(DENSE, m, 3, 2) == want
 
 
 def test_mfu_reader_by_hand():
@@ -235,8 +237,8 @@ def test_mfu_reader_by_hand():
     read = harness.metric_reader("step_mfu")
     req = NS(prompt=[0] * 100, out_tokens=[1] * 10)
     view = NS(records=[NS(requests=[req, req])], window_s=2.0, model=m,
-              peaks=peaks.peaks_for("TPU v5 lite"))
-    want = 2 * counts.request_flops(m, 100, 10) / 2.0 / 197e12 * 100
+              arch=DENSE, peaks=peaks.peaks_for("TPU v5 lite"))
+    want = 2 * counts.request_flops(DENSE, m, 100, 10) / 2.0 / 197e12 * 100
     assert read(view) == pytest.approx(want)
 
 
