@@ -2,6 +2,12 @@
 
 A block = pre-norm mixer (attention / mamba / parallel-hybrid) + residual,
 then (if the config has an FFN) pre-norm FFN (dense MLP or MoE) + residual.
+With ``cfg.parallel_block`` attention and FFN both read the one pre-norm
+output and add to the same residual: ``x + attn(h) + ffn(h)`` (Phi).
+
+The norms, the attention-only mixer and the FFN run under
+``jax.named_scope``s ``norm``, ``attn`` and ``mlp``, so compiled op names
+say which part an op belongs to.
 """
 from __future__ import annotations
 
@@ -12,9 +18,22 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.attention import attn_block
-from repro.models.layers import glu_mlp, rms_norm
+from repro.models.layers import glu_mlp, layer_norm, rms_norm
 from repro.models.moe import moe_block
 from repro.models.ssm import mamba_block
+
+# ``jax.random.fold_in`` data of the key that draws a block's (or the top
+# level's) norm and bias leaves: with jax's partitionable threefry,
+# ``split(k, n)[i] == fold_in(k, i)``, so this lies far above every split
+# count of the same key and leaves the keys of every other leaf as they are
+AFFINE_KEY_DATA = 0x51CE
+# biases and gain offsets: never zero, and as small as the source's own
+# initializer_range (0.02 for Phi), so that summed over the residual
+# stream they do not swamp what each token brings (at 0.1 the logits of a
+# random 24-layer model hardly depend on the token)
+AFFINE_STD = 0.02
+# the head's bias sums over nothing and meets logits of std ~1
+HEAD_BIAS_STD = 0.1
 
 
 def _init_linear(key, din, dout, scale=None, dtype=jnp.float32):
@@ -93,6 +112,48 @@ def init_mamba_params(key, cfg, dtype=jnp.float32) -> dict:
     }
 
 
+def affine_leaves(key, shapes: dict) -> dict:
+    """Norm and bias leaves drawn from ``key`` (small normals, so that
+    every one of them moves the output), in the order of ``shapes``."""
+    ks = jax.random.split(jax.random.fold_in(key, AFFINE_KEY_DATA),
+                          len(shapes))
+    return {name: jax.random.normal(k, shape, jnp.float32)
+            * (HEAD_BIAS_STD if name == "lm_head_bias" else AFFINE_STD)
+            for k, (name, shape) in zip(ks, shapes.items())}
+
+
+def _drawn_block_leaves(key, cfg, p: dict) -> None:
+    """Phi's leaves: LayerNorm scales and biases, and a bias on every
+    projection (the qkv biases that ``qkv_bias`` alone keeps at zero are
+    drawn too). ``ModelConfig`` allows ``linear_bias`` only on attention
+    blocks with a dense FFN."""
+    d = cfg.d_model
+    shapes = {}
+    norms = [n for n in ("norm1", "norm2") if n in p]
+    if cfg.norm == "layer":
+        for n in norms:
+            shapes[n] = (d,)
+            shapes[f"{n}_bias"] = (d,)
+    if cfg.linear_bias:
+        shapes.update(bq=(cfg.attn_dim,), bk=(cfg.kv_dim,),
+                      bv=(cfg.kv_dim,), bo=(d,))
+        if "ffn" in p:
+            shapes.update(b_up=(cfg.d_ff,), b_down=(d,))
+    for name, leaf in affine_leaves(key, shapes).items():
+        if name in ("bq", "bk", "bv", "bo"):
+            p["attn"][name] = leaf
+        elif name in ("b_up", "b_down"):
+            p["ffn"][name] = leaf
+        else:
+            p[name] = leaf
+
+
+def block_norm(x: jax.Array, p: dict, name: str, cfg) -> jax.Array:
+    if cfg.norm == "layer":
+        return layer_norm(x, p[name], p[f"{name}_bias"], cfg.norm_eps)
+    return rms_norm(x, p[name], cfg.norm_eps)
+
+
 def init_block(key, kind: str, use_moe: bool, cfg, dtype=jnp.float32) -> dict:
     ks = jax.random.split(key, 3)
     p = {"norm1": jnp.zeros((cfg.d_model,), jnp.float32)}
@@ -106,9 +167,12 @@ def init_block(key, kind: str, use_moe: bool, cfg, dtype=jnp.float32) -> dict:
     else:
         raise ValueError(kind)
     if cfg.d_ff > 0:
-        p["norm2"] = jnp.zeros((cfg.d_model,), jnp.float32)
+        if not cfg.parallel_block:
+            p["norm2"] = jnp.zeros((cfg.d_model,), jnp.float32)
         p["ffn"] = (init_moe_params(ks[2], cfg, dtype) if use_moe
                     else init_mlp_params(ks[2], cfg, dtype))
+    if cfg.norm == "layer" or cfg.linear_bias:
+        _drawn_block_leaves(key, cfg, p)
     return p
 
 
@@ -122,7 +186,8 @@ def apply_block(p: dict, x: jax.Array, kind: str, use_moe: bool, cfg, *,
                 ) -> Tuple[jax.Array, Optional[dict], jax.Array]:
     """Returns (x_out, new_cache, moe_aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
-    h = rms_norm(x, p["norm1"], cfg.norm_eps)
+    with jax.named_scope("norm"):
+        h = block_norm(x, p, "norm1", cfg)
     new_cache = {}
     window = cfg.window if kind.endswith("_local") else None
     # attention takes valid_len as an ABSOLUTE position bound (chunked
@@ -132,13 +197,14 @@ def apply_block(p: dict, x: jax.Array, kind: str, use_moe: bool, cfg, *,
                  if valid_len is not None else None)
 
     if kind.startswith("attn"):
-        mix, ac = attn_block(p["attn"], h, cfg, positions=positions,
-                             window=window,
-                             cache=cache.get("attn") if cache else None,
-                             pos=pos, valid_len=valid_len,
-                             tap=_sub(tap, "attn"),
-                             use_pallas=use_pallas,
-                             paged_attention=paged_attention)
+        with jax.named_scope("attn"):
+            mix, ac = attn_block(p["attn"], h, cfg, positions=positions,
+                                 window=window,
+                                 cache=cache.get("attn") if cache else None,
+                                 pos=pos, valid_len=valid_len,
+                                 tap=_sub(tap, "attn"),
+                                 use_pallas=use_pallas,
+                                 paged_attention=paged_attention)
         if ac is not None:
             new_cache["attn"] = ac
     elif kind == "mamba":
@@ -171,13 +237,16 @@ def apply_block(p: dict, x: jax.Array, kind: str, use_moe: bool, cfg, *,
     x = x + mix
 
     if "ffn" in p:
-        h = rms_norm(x, p["norm2"], cfg.norm_eps)
-        if use_moe:
-            y, aux = moe_block(p["ffn"], h, cfg, tap=_sub(tap, "moe"),
-                               use_pallas=use_pallas)
-        else:
-            y = glu_mlp(h, p["ffn"], cfg.act, cfg.gated_mlp,
-                        use_pallas=use_pallas, tap=_sub(tap, "ffn"))
+        if not cfg.parallel_block:   # else the FFN reads the mixer's norm
+            with jax.named_scope("norm"):
+                h = block_norm(x, p, "norm2", cfg)
+        with jax.named_scope("mlp"):
+            if use_moe:
+                y, aux = moe_block(p["ffn"], h, cfg, tap=_sub(tap, "moe"),
+                                   use_pallas=use_pallas)
+            else:
+                y = glu_mlp(h, p["ffn"], cfg.act, cfg.gated_mlp,
+                            use_pallas=use_pallas, tap=_sub(tap, "ffn"))
         x = x + y
     return x, (new_cache or None), aux
 

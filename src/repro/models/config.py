@@ -13,6 +13,18 @@ attn_local    sliding-window self-attention mixer (+ FFN)
 mamba         Mamba2 SSD mixer (+ FFN if d_ff > 0)
 hybrid        parallel attn + SSM heads, outputs fused (Hymba-style)
 hybrid_local  same with sliding-window attention
+
+Block form
+----------
+parallel_block  False: x + mix(norm1(x)), then x + ffn(norm2(x));
+                True: x + mix(norm1(x)) + ffn(norm1(x)), one norm a layer
+                (Phi, GPT-J)
+norm            "rms" (RMSNorm) or "layer" (LayerNorm with a bias); apart
+                from parallel_block, since StableLM-2 as published is the
+                sequential block with LayerNorm
+linear_bias     biases on every projection (q, k, v, o, MLP up and down)
+                and on the LM head, so it implies qkv_bias's
+parallel_block and linear_bias need an attention-only, dense-FFN stack.
 """
 from __future__ import annotations
 
@@ -71,6 +83,9 @@ class ModelConfig:
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
     qkv_bias: bool = False
+    parallel_block: bool = False
+    norm: str = "rms"                # rms | layer
+    linear_bias: bool = False
 
     # quantized serving: "fp16" | "qtensor"
     serve_weights: str = "fp16"
@@ -93,6 +108,17 @@ class ModelConfig:
                 f"pattern length {len(self.pattern)}")
         if len(self.moe_pattern) not in (1, len(self.pattern)):
             raise ValueError("moe_pattern must match pattern length (or 1)")
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"{self.name}: norm={self.norm!r}")
+        if (self.parallel_block or self.linear_bias) and (
+                any(not k.startswith("attn") for k in self.pattern)
+                or any(self.moe_slots)):
+            raise ValueError(f"{self.name}: parallel_block and linear_bias "
+                             f"need an attention-only, dense-FFN pattern")
+        if self.is_encdec and (self.parallel_block or self.linear_bias
+                               or self.norm != "rms"):
+            raise ValueError(f"{self.name}: the encoder-decoder path has "
+                             f"only the sequential RMSNorm block")
 
     @property
     def n_groups(self) -> int:
@@ -138,10 +164,20 @@ class ModelConfig:
         n = v * d                                   # embedding
         if not self.tie_embeddings:
             n += d * v                              # lm head
+            if self.linear_bias:
+                n += v                              # lm head bias
+        # one norm leaf (two with a LayerNorm's bias) before each mixer
+        # and each FFN, none before the FFN of a parallel block
+        norm_p = 2 * d if self.norm == "layer" else d
         per_kind = {}
         attn_p = d * self.attn_dim + 2 * d * self.kv_dim \
-            + self.attn_dim * d + d
-        mlp_p = ((3 if self.gated_mlp else 2) * d * ff + d) if ff else 0
+            + self.attn_dim * d + norm_p
+        if self.linear_bias:
+            # q, k, v and o; qkv_bias alone has never been counted
+            attn_p += self.attn_dim + 2 * self.kv_dim + d
+        mlp_p = ((3 if self.gated_mlp else 2) * d * ff
+                 + (0 if self.parallel_block else norm_p)
+                 + (ff + d if self.linear_bias else 0)) if ff else 0
         moe_p = (d * self.n_experts
                  + self.n_experts * (3 if self.gated_mlp else 2) * d * ff
                  + d) if self.n_experts else 0
@@ -166,7 +202,7 @@ class ModelConfig:
             enc_p = attn_p + mlp_p
             cross_p = attn_p
             n += self.n_enc_layers * enc_p + self.n_layers * cross_p
-        n += d  # final norm
+        n += norm_p  # final norm
         return n
 
     def active_param_count(self) -> int:

@@ -23,6 +23,19 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
         jnp.float32))).astype(dt)
 
 
+def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
+               eps: float = 1e-5) -> jax.Array:
+    """LayerNorm with a bias. Like ``rms_norm``, the stored scale is the
+    gain's offset from 1."""
+    dt = x.dtype
+    x = x.astype(jnp.float32)
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+    return ((x - mean) * jax.lax.rsqrt(var + eps)
+            * (1.0 + scale.astype(jnp.float32))
+            + bias.astype(jnp.float32)).astype(dt)
+
+
 def matmul_any(x: jax.Array, w, use_pallas: bool = False,
                tp_dim: int = 1) -> jax.Array:
     """x @ w where w is dense, a QTensor, a ShardedQTensor or a CodePlane
@@ -143,15 +156,17 @@ def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
 
 def glu_mlp(x: jax.Array, p: dict, act: str = "silu", gated: bool = True,
             use_pallas: bool = False, tap=None) -> jax.Array:
+    # "gelu" is jax's default tanh form (HF's "gelu_new")
     actf = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
             "relu": jax.nn.relu}[act]
     if tap:
         tap("w_up", x)
     if gated:
         h = actf(linear(x, p["w_gate"], use_pallas=use_pallas)) \
-            * linear(x, p["w_up"], use_pallas=use_pallas)
+            * linear(x, p["w_up"], p.get("b_up"), use_pallas=use_pallas)
     else:
-        h = actf(linear(x, p["w_up"], use_pallas=use_pallas))
+        h = actf(linear(x, p["w_up"], p.get("b_up"), use_pallas=use_pallas))
     if tap:
         tap("w_down", h)
-    return linear(h, p["w_down"], use_pallas=use_pallas, tp_dim=0)
+    return linear(h, p["w_down"], p.get("b_down"), use_pallas=use_pallas,
+                  tp_dim=0)

@@ -39,6 +39,13 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     if not cfg.tie_embeddings:
         params["lm_head"] = (jax.random.normal(keys[1], (d, cfg.vocab))
                              * (d ** -0.5)).astype(dtype)
+    drawn = {}
+    if cfg.norm == "layer":
+        drawn.update(final_norm=(d,), final_norm_bias=(d,))
+    if cfg.linear_bias and not cfg.tie_embeddings:
+        drawn["lm_head_bias"] = (cfg.vocab,)
+    if drawn:
+        params.update(B.affine_leaves(key, drawn))
 
     # decoder blocks, stacked over groups
     def one_group(gkey):
@@ -284,16 +291,20 @@ def forward(cfg: ModelConfig, params, tokens: jax.Array, *,
                                                 *ncs)
                          if ncs and ncs[0] is not None else None)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("norm"):
+        x = B.block_norm(x, params, "final_norm", cfg)
     if n_vis:
         x = x[:, n_vis:]
     if skip_head:
         return x, new_cache, aux_total
-    if cfg.tie_embeddings:
-        logits = jnp.matmul(x, params["embed"]["tok"].T.astype(x.dtype))
-    else:
-        logits = linear(x, params["lm_head"], use_pallas=use_pallas)
-    logits = softcap(logits, cfg.logit_softcap)
+    with jax.named_scope("head"):
+        if cfg.tie_embeddings:
+            logits = jnp.matmul(x, params["embed"]["tok"].T.astype(x.dtype))
+        else:
+            logits = linear(x, params["lm_head"],
+                            params.get("lm_head_bias"),
+                            use_pallas=use_pallas)
+        logits = softcap(logits, cfg.logit_softcap)
     return logits, new_cache, aux_total
 
 
@@ -318,7 +329,9 @@ def _make_tap(taps, layer_idx):
 def train_loss(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
                use_pallas: bool = False, scan_layers: bool = True,
                remat: bool = True) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    if cfg.chunked_ce and batch.get("loss_mask") is None:
+    # the chunked loss fuses a bias-free head; a head bias takes the plain one
+    if (cfg.chunked_ce and batch.get("loss_mask") is None
+            and "lm_head_bias" not in params):
         from repro.models.chunked_ce import sharded_ce_loss
         hidden, _, aux = forward(
             cfg, params, batch["tokens"],

@@ -48,7 +48,8 @@ def test_smoke_train_step(arch):
 @pytest.mark.parametrize("arch", ["stablelm-1.6b", "gemma2-2b",
                                   "mamba2-370m", "jamba-1.5-large-398b",
                                   "dbrx-132b", "whisper-medium",
-                                  "internvl2-2b", "hymba-1.5b"])
+                                  "internvl2-2b", "hymba-1.5b",
+                                  "phi-1.5b"])
 def test_smoke_decode_matches_forward(arch):
     import dataclasses
     # capacity drops legitimately differ between full-forward (B*S tokens)
