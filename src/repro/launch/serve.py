@@ -185,7 +185,8 @@ def build_engine(args, cfg, params, tracer=None) -> ServeEngine:
         cfg, mesh, p_struct, page=args.page_size,
         n_pages=n_pages, max_slots=args.slots,
         max_pages_per_seq=mpps, chunk=chunk,
-        paged_attention=args.paged_attention)
+        paged_attention=args.paged_attention,
+        select_width=1 + args.speculative)
     sp = SamplingParams(temperature=args.temperature, top_k=args.top_k,
                         top_p=args.top_p, seed=args.seed,
                         logprobs=args.logprobs)

@@ -297,6 +297,15 @@ def forward(cfg: ModelConfig, params, tokens: jax.Array, *,
         x = x[:, n_vis:]
     if skip_head:
         return x, new_cache, aux_total
+    return apply_head(cfg, params, x, use_pallas), new_cache, aux_total
+
+
+def apply_head(cfg: ModelConfig, params, x: jax.Array,
+               use_pallas: bool = False) -> jax.Array:
+    """The LM head on final-norm hidden states ``x [..., d]``: the tied
+    embedding or ``lm_head`` (with ``lm_head_bias`` where the config has
+    one), then the logit softcap. ``forward`` ends with it; the serving
+    step runs it on the few rows that emit a token."""
     with jax.named_scope("head"):
         if cfg.tie_embeddings:
             logits = jnp.matmul(x, params["embed"]["tok"].T.astype(x.dtype))
@@ -304,8 +313,7 @@ def forward(cfg: ModelConfig, params, tokens: jax.Array, *,
             logits = linear(x, params["lm_head"],
                             params.get("lm_head_bias"),
                             use_pallas=use_pallas)
-        logits = softcap(logits, cfg.logit_softcap)
-    return logits, new_cache, aux_total
+        return softcap(logits, cfg.logit_softcap)
 
 
 def _make_tap(taps, layer_idx):

@@ -206,6 +206,9 @@ class EngineStats:
     # live lanes: 1 - useful / computed is the padded share
     positions_computed: int = 0
     positions_useful: int = 0
+    # rows x W of every launched step: the hidden rows the LM head and
+    # token selection ran on, W = min(C, select_width) columns a lane
+    head_rows: int = 0
     # the weight execution plan (core/serving_quant.exec_weight_counts):
     # bytes of the matmul weights the step reads, and how many QMC leaves
     # it reads as int8 code planes / matmul weights it reads dense
@@ -539,11 +542,12 @@ class ServeEngine:
                         max_slots=slots,
                         max_pages_per_seq=self.max_pages_per_seq,
                         cache_dtype=cache_dtype, chunk=self.chunk,
-                        paged_attention=paged_attention):
+                        paged_attention=paged_attention,
+                        select_width=1 + self._spec_k):
                 raise ValueError(
                     "step_set was built for a different engine geometry "
-                    "(cfg/mesh/page/n_pages/slots/cache_dtype/chunk must "
-                    "match)")
+                    "(cfg/mesh/page/n_pages/slots/cache_dtype/chunk/"
+                    "select_width must match)")
         self._steps = step_set
         # serving weight plan (core/serving_quant.build_exec_weights):
         # the stream-format tree stays the source of truth (self.params,
@@ -588,7 +592,8 @@ class ServeEngine:
             n_pages=self.n_pages, max_slots=self.slots,
             max_pages_per_seq=self.max_pages_per_seq,
             cache_dtype=self.cache_dtype, chunk=self.chunk,
-            paged_attention=self.paged_attention)
+            paged_attention=self.paged_attention,
+            select_width=1 + self._spec_k)
 
     def _ensure_pool(self) -> PagedKVPool:
         if self._pool is None:
@@ -1039,6 +1044,8 @@ class ServeEngine:
             c_len = toks.shape[1]
             self.stats.positions_computed += rows * c_len
             self.stats.positions_useful += int(n_new.sum())
+            self.stats.head_rows += rows * min(c_len,
+                                               self._steps.select_width)
             # the sampling lanes are copied (jnp.array): set_lane
             # rewrites them in place, see _flush_page_ops
             with part("round/step/upload", t0=t0) as upload:

@@ -11,10 +11,10 @@ Both consumers of the serve subsystem go through this module:
 
 Every builder takes ``(cfg, mesh, params_struct)``. With ``mesh=None`` the
 builders emit plain single-device ``jax.jit`` functions (lru-shared per
-FULL step geometry — cfg, page, pool/slot sizes, cache dtype, chunk and
-the ``paged_attention`` flag are all part of the cache key, so a late
-flag flip can never reuse a stale jit). With a mesh they emit jit
-functions with **explicit input/output shardings**.
+FULL step geometry — cfg, page, pool/slot sizes, cache dtype, chunk, the
+``paged_attention`` flag and the select width are all part of the cache
+key, so a late flag flip can never reuse a stale jit). With a mesh they
+emit jit functions with **explicit input/output shardings**.
 
 The unified step contract
 -------------------------
@@ -35,11 +35,14 @@ The unified step contract
   at admission (``None`` for attention-only stacks); ``page_copy`` is
   the device half of ``PagedKVPool.cow``.
 
-  **Sampling head** — token selection is FUSED into the step: the raw
-  ``[B, C, V]`` logits never cross the jit boundary (they used to feed
-  a stray out-of-jit ``jnp.argmax`` dispatch per round, invisible to
-  cost attribution). ``sampling`` is a pytree of traced ``[B]`` lane
-  params — ``{"temp" f32, "top_k" i32, "top_p" f32, "key" [B,2] u32}``
+  **Sampling head** — token selection is FUSED into the step: logits
+  never cross the jit boundary (they used to feed a stray out-of-jit
+  ``jnp.argmax`` dispatch per round, invisible to cost attribution).
+  The LM head and the selection run only on the columns a lane can
+  emit from: its last ``W = min(C, select_width)`` live columns, with
+  ``select_width = 1 + speculative_k`` part of the step geometry, so a
+  1,024-wide prefill computes ``[B, W, V]`` logits, not ``[B, C, V]``.
+  ``sampling`` is a pytree of traced ``[B]`` lane params — ``{"temp" f32, "top_k" i32, "top_p" f32, "key" [B,2] u32}``
   (see ``serve.sampling.lane_inputs``) — so one compile per width C
   serves every parameter combo. A ``temp <= 0`` lane takes the argmax
   path bitwise (greedy stays the oracle); sampled lanes draw via
@@ -48,7 +51,8 @@ The unified step contract
   independent streams — see ``serve/sampling.py`` for the full
   contract). The returned logprobs are the model-distribution
   log-softmax at the selected token; dead columns (at or past
-  ``n_new[b]``) return ``sampling.DEAD_TOKEN`` = -1, never a vocab id.
+  ``n_new[b]``, or before the lane's window) return
+  ``sampling.DEAD_TOKEN`` = -1 and logprob 0.0, never a vocab id.
 
   **Verify steps** (self-speculative decode) are the SAME step at the
   same rungs: a lane verifying k draft tokens runs ``n_new = 1 + k``
@@ -133,6 +137,7 @@ from repro.launch import mesh as meshlib
 from repro.launch import sharding as shd
 from repro.models import kvcache as KV
 from repro.models.config import ModelConfig
+from repro.models.model import apply_head
 from repro.models.model import decode_step as _decode
 from repro.models.model import forward as _forward
 from repro.models.model import prefill as _prefill
@@ -401,16 +406,20 @@ class PagedServeSteps:
     apply_page_ops: Optional[Callable] = None
     solo_step: Optional[Callable] = None
     paged_attention: bool = False    # attention via the ragged Pallas kernel
+    # columns per lane the head and token selection run on: the most
+    # tokens a lane can emit in one step (1 + speculative_k)
+    select_width: int = 1
 
     def compatible_with(self, *, page, n_pages, max_slots,
                         max_pages_per_seq, cache_dtype, chunk,
-                        paged_attention=False) -> bool:
+                        paged_attention=False, select_width=1) -> bool:
         return (self.page == page and self.n_pages == n_pages
                 and self.max_slots == max_slots
                 and self.max_pages_per_seq == max_pages_per_seq
                 and self.cache_dtype == cache_dtype
                 and self.chunk == chunk
-                and self.paged_attention == paged_attention)
+                and self.paged_attention == paged_attention
+                and self.select_width == select_width)
 
     def jit_counters(self) -> Tuple[int, int, float]:
         """Aggregate (calls, compiles, compile_seconds) over this step
@@ -486,7 +495,8 @@ def build_paged_steps(cfg: ModelConfig, mesh=None, params_struct=None, *,
                       max_pages_per_seq: int,
                       cache_dtype=jnp.float32,
                       chunk: Optional[int] = None,
-                      paged_attention: bool = False) -> PagedServeSteps:
+                      paged_attention: bool = False,
+                      select_width: int = 1) -> PagedServeSteps:
     """Build the full paged serving step set for one engine geometry.
 
     ``chunk`` is the prefill chunk width (the ``C > 1`` step shape); the
@@ -510,7 +520,14 @@ def build_paged_steps(cfg: ModelConfig, mesh=None, params_struct=None, *,
     the arena geometry must divide the mesh (``shard_compatible``), which
     ``default_n_pages`` guarantees for the page axis; unsupported
     geometries fall back to the XLA gather inside the traced step.
+
+    ``select_width`` is how many columns a lane can emit from in one
+    step, ``1 + speculative_k``: the LM head and token selection run on
+    each lane's last ``min(C, select_width)`` live columns only
+    (``_step_body``).
     """
+    if select_width < 1:
+        raise ValueError(f"select_width must be >= 1, got {select_width}")
     if chunk is None:
         chunk = default_chunk(max_pages_per_seq, page)
     # one engine drives the decode width (C = 1) plus the pow2 prefill
@@ -520,12 +537,12 @@ def build_paged_steps(cfg: ModelConfig, mesh=None, params_struct=None, *,
     if mesh is None:
         step, page_copy, reset, apply_ops, solo = _single_device_steps(
             cfg, page, n_pages, max_slots, max_pages_per_seq,
-            cache_dtype, chunk, paged_attention)
+            cache_dtype, chunk, paged_attention, select_width)
         return PagedServeSteps(
             cfg=cfg, mesh=None, page=page, n_pages=n_pages,
             max_slots=max_slots, max_pages_per_seq=max_pages_per_seq,
             cache_dtype=cache_dtype, chunk=chunk,
-            paged_attention=paged_attention,
+            paged_attention=paged_attention, select_width=select_width,
             step=TracedJit("step", step, step_shapes,
                            cost_key=_step_cost_key),
             page_copy=TracedJit("page_copy", page_copy, 1),
@@ -552,7 +569,7 @@ def build_paged_steps(cfg: ModelConfig, mesh=None, params_struct=None, *,
     # traced sampling lane params: [B] knobs shard with the batch, the
     # [B, 2] raw key rides the token spec; [B, C] outputs likewise
     samp_sh = {"temp": b_sh, "top_k": b_sh, "top_p": b_sh, "key": tok_sh}
-    step_body = _step_body(cfg, paged_attention)
+    step_body = _step_body(cfg, paged_attention, select_width)
 
     def step_fn(params, tokens, arena, start, n_new, samp):
         with ctx.use_mesh(mesh, dp):
@@ -569,7 +586,7 @@ def build_paged_steps(cfg: ModelConfig, mesh=None, params_struct=None, *,
         cfg=cfg, mesh=mesh, page=page, n_pages=n_pages,
         max_slots=max_slots, max_pages_per_seq=max_pages_per_seq,
         cache_dtype=cache_dtype, chunk=chunk,
-        paged_attention=paged_attention,
+        paged_attention=paged_attention, select_width=select_width,
         step=TracedJit(
             "step",
             jax.jit(step_fn,
@@ -595,7 +612,7 @@ def build_paged_steps(cfg: ModelConfig, mesh=None, params_struct=None, *,
 # step bodies (shared by the mesh-less lru-cached jits and the sharded
 # builders above)
 # --------------------------------------------------------------------------
-def _step_body(cfg: ModelConfig, paged_attention: bool):
+def _step_body(cfg: ModelConfig, paged_attention: bool, select_width: int):
     """The ONE serving step: ragged chunked prefill + batched decode.
 
     ``tokens [B, C]`` are lane-local new tokens; lane ``b`` runs its
@@ -603,21 +620,43 @@ def _step_body(cfg: ModelConfig, paged_attention: bool):
     ``valid_len = start + n_new`` masks reads past each lane's bound,
     routes right-padding K/V writes to the null page, and (converted to
     a relative count inside ``blocks.apply_block``) keeps recurrent SSM
-    state clean for idle and padded lanes. The fused
-    ``sampling.select_tokens`` epilogue turns the logits into selected
-    token ids + logprobs before anything leaves the jit."""
+    state clean for idle and padded lanes.
+
+    The LM head and the fused ``sampling.select_tokens`` epilogue run
+    only on each lane's last ``W = min(C, select_width)`` live columns
+    (the window starts at ``max(n_new - W, 0)``): a prefill lane emits
+    from its last column, a verify lane from its ``1 + k`` columns, a
+    decode lane from its one. The selected tokens and logprobs scatter
+    back into ``[B, C]``; every column outside the window reads
+    ``DEAD_TOKEN`` / 0.0. At ``W == C`` (every decode step) the window
+    is the whole step and nothing is gathered."""
 
     def step(params, tokens, arena, start, n_new, samp):
         c = tokens.shape[1]
+        w = min(c, select_width)
         positions = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
         valid = start + n_new
-        logits, new_arena, _ = _forward(cfg, params, tokens,
+        hidden, new_arena, _ = _forward(cfg, params, tokens,
                                         positions=positions, cache=arena,
-                                        valid_len=valid,
+                                        valid_len=valid, skip_head=True,
                                         paged_attention=paged_attention)
+        if w < c:
+            off = jnp.maximum(n_new - w, 0)
+            take = jax.vmap(
+                lambda a, o: jax.lax.dynamic_slice_in_dim(a, o, w, axis=0))
+            hidden, positions = take(hidden, off), take(positions, off)
+        logits = apply_head(cfg, params, hidden)
         tok, logp = sampling.select_tokens(
             logits, samp["temp"], samp["top_k"], samp["top_p"],
             samp["key"], positions, n_new)
+        if w < c:
+            put = jax.vmap(lambda full, a, o:
+                           jax.lax.dynamic_update_slice_in_dim(full, a, o,
+                                                               axis=0))
+            b = tokens.shape[0]
+            tok = put(jnp.full((b, c), sampling.DEAD_TOKEN, tok.dtype),
+                      tok, off)
+            logp = put(jnp.zeros((b, c), logp.dtype), logp, off)
         return tok, logp, new_arena
 
     return step
@@ -688,7 +727,8 @@ def _apply_page_ops_body(cfg: ModelConfig):
     return _apply
 
 
-def _solo_step_body(cfg: ModelConfig, paged_attention: bool):
+def _solo_step_body(cfg: ModelConfig, paged_attention: bool,
+                    select_width: int):
     """Single-live-lane round at batch width 1 (see module docstring).
 
     The slot's per-slot rows (``block_tbl``, SSM, conv) are dynamic-
@@ -697,7 +737,7 @@ def _solo_step_body(cfg: ModelConfig, paged_attention: bool):
     step's K/V writes land in the real arena pages directly. The block
     table is read-only inside the step, so the full-width original is
     kept on the way out."""
-    step = _step_body(cfg, paged_attention)
+    step = _step_body(cfg, paged_attention, select_width)
 
     def solo(params, tokens, arena, slot, start, n_new, samp):
         view = {}
@@ -769,16 +809,19 @@ def _reset_state_body(cfg: ModelConfig):
 @functools.lru_cache(maxsize=None)
 def _single_device_steps(cfg: ModelConfig, page: int, n_pages: int,
                          max_slots: int, max_pages_per_seq: int,
-                         cache_dtype, chunk: int, paged_attention: bool):
+                         cache_dtype, chunk: int, paged_attention: bool,
+                         select_width: int):
     """Single-device jits, cached on the FULL step geometry.
 
     The key is exactly the tuple ``PagedServeSteps.compatible_with``
     checks — a flag (or geometry knob) passed late can never silently
     reuse a jit traced for a different configuration."""
-    step = jax.jit(_step_body(cfg, paged_attention), donate_argnums=2)
+    step = jax.jit(_step_body(cfg, paged_attention, select_width),
+                   donate_argnums=2)
     page_copy = jax.jit(_page_copy_body(cfg), donate_argnums=0)
     apply_ops = jax.jit(_apply_page_ops_body(cfg), donate_argnums=0)
-    solo = jax.jit(_solo_step_body(cfg, paged_attention), donate_argnums=2)
+    solo = jax.jit(_solo_step_body(cfg, paged_attention, select_width),
+                   donate_argnums=2)
     reset = None
     if any(k == "mamba" or k.startswith("hybrid") for k in cfg.pattern):
         reset = jax.jit(_reset_state_body(cfg), donate_argnums=0)

@@ -28,8 +28,7 @@ from repro.core.serving_quant import (build_exec_weights,
                                       serving_params_struct)
 from repro.models import blocks
 from repro.models.layers import rms_norm
-from repro.models.model import forward, init_params
-from repro.serve import sampling
+from repro.models.model import apply_head, forward, init_params
 from repro.serve import steps as serve_steps
 from repro.serve.engine import Request, ServeEngine
 
@@ -74,20 +73,25 @@ def served_dense(qparams):
 @pytest.fixture
 def step_logits(monkeypatch):
     """Logits of every live column of every step, by absolute position
-    (one request at a time, so lane 0), recorded from inside the step."""
+    (one request at a time, so lane 0), recorded from inside the step:
+    the head over the final hidden states of all columns (the step
+    itself runs it only on the columns that emit)."""
     seen = []
-    real = sampling.select_tokens
+    real = serve_steps._forward
 
     def record(logits, positions, n_new):
         n = int(n_new[0])
         for t in range(n):
             seen.append((int(positions[0, t]), np.asarray(logits[0, t])))
 
-    def select(logits, temp, top_k, top_p, key, positions, n_new):
-        jax.debug.callback(record, logits, positions, n_new)
-        return real(logits, temp, top_k, top_p, key, positions, n_new)
+    def forward_all(cfg, params, tokens, **kw):
+        out = real(cfg, params, tokens, **kw)
+        positions = kw["positions"]
+        jax.debug.callback(record, apply_head(cfg, params, out[0]),
+                           positions, kw["valid_len"] - positions[:, 0])
+        return out
 
-    monkeypatch.setattr(sampling, "select_tokens", select)
+    monkeypatch.setattr(serve_steps, "_forward", forward_all)
     serve_steps._single_device_steps.cache_clear()
     yield seen
     serve_steps._single_device_steps.cache_clear()

@@ -225,7 +225,9 @@ def test_head_hypothesis_mass_properties():
 # ==========================================================================
 def test_step_returns_tokens_not_logits(serve_cfg, serve_params):
     """Direct step call: outputs are [B, C] int32 / float32 — no vocab
-    axis crosses the boundary — and an idle lane reads DEAD_TOKEN."""
+    axis crosses the boundary — an idle lane reads DEAD_TOKEN, and a
+    prefill lane selects a token only at its last column (the one it
+    emits from; the head runs on the last ``select_width`` columns)."""
     eng = _engine(serve_cfg, serve_params)
     eng.run([Request(uid=0, prompt=np.arange(2, 6, dtype=np.int32),
                      max_new_tokens=1)])     # materializes pool + arena
@@ -241,7 +243,10 @@ def test_step_returns_tokens_not_logits(serve_cfg, serve_params):
     assert logp.shape == (SLOTS, c) and logp.dtype == jnp.float32
     tok = np.asarray(tok)
     assert (tok[1:] == DEAD_TOKEN).all()
-    assert ((0 <= tok[0]) & (tok[0] < serve_cfg.vocab)).all()
+    assert steps.select_width == 1
+    assert (tok[0, :-1] == DEAD_TOKEN).all()
+    assert 0 <= tok[0, -1] < serve_cfg.vocab
+    assert (np.asarray(logp)[0, :-1] == 0.0).all()
 
 
 def test_one_attributed_dispatch_per_round(serve_cfg, serve_params):
